@@ -16,7 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import fused_exec as fk
-from repro.kernels import ops, ref
+from repro.kernels import ops
 from repro.kernels.runtime import has_compiled_backend
 
 PEAK = 197e12
@@ -39,41 +39,24 @@ def main(smoke: bool = False) -> List[str]:
     rng = np.random.default_rng(0)
     out = []
 
-    # bm25_topk: P postings
-    for p in (1 << 14,) if smoke else (1 << 14, 1 << 17):
-        docs = jnp.asarray(np.sort(rng.choice(p * 4, p, replace=False)).astype(np.int32))
-        freqs = jnp.asarray(rng.integers(1, 30, p).astype(np.int32))
-        dl = jnp.asarray(rng.integers(10, 500, p * 4).astype(np.int32))
-        live = jnp.asarray(np.ones(p * 4, bool))
-        t = _time(
-            lambda: ops.bm25_topk(docs, freqs, dl, live, 2.0, 120.0, 0.9, 0.4, 10),
-            reps=reps,
-        )
-        bytes_touched = p * (4 + 4 + 4 + 1)  # freqs, dl, docs, valid
-        roof = max(p * 8 / PEAK, bytes_touched / BW)
-        out.append(
-            f"bm25_topk,P={p},{t*1e6:.0f},us_cpu_interp"
-            f";tpu_roofline_us={roof*1e6:.2f},bytes={bytes_touched}"
-        )
-
-    # fused term executor kernel: gathered postings tiles + BM25 + live
-    # mask + per-block top-k in one pallas_call (the tentpole's term path)
+    # fused term executor kernel: packed dl|live words of the gathered
+    # postings + freqs tiles -> BM25 + live mask + per-block top-k in one
+    # pallas_call (the doc-side gather runs in the caller's XLA prologue)
     for bsz, p in ((8, 4096),) if smoke else ((8, 4096), (32, 8192)):
-        nd = p * 2
-        f_docs = jnp.asarray(rng.integers(0, nd, (bsz, p)).astype(np.int32))
+        f_g = jnp.asarray(
+            (rng.integers(10, 500, (bsz, p)) << 1 | 1).astype(np.int32)
+        )
         f_freqs = jnp.asarray(rng.integers(1, 30, (bsz, p)).astype(np.int32))
-        f_dl = jnp.asarray(rng.integers(10, 500, nd).astype(np.int32))
-        f_live = jnp.asarray(np.ones(nd, np.int32))
         idfs = jnp.asarray(rng.uniform(0.5, 4.0, bsz).astype(np.float32))
         interp = not has_compiled_backend()
         t = _time(
             lambda: fk.term_topk_tiles(
-                f_docs, f_freqs, f_dl, f_live, idfs, 120.0, 0.9, 0.4, 10, interp
+                f_g, f_freqs, idfs, 120.0, 0.9, 0.4, 10, interp
             ),
             reps=reps,
         )
-        # docs + freqs tile reads + dl/live doc-side gathers, per lane
-        bytes_touched = bsz * p * (4 + 4 + 4 + 4)
+        # packed dl|live + freqs tile reads, per lane
+        bytes_touched = bsz * p * (4 + 4)
         roof = max(bsz * p * 8 / PEAK, bytes_touched / BW)
         mode = "us_cpu_interp" if interp else "us_compiled"
         out.append(

@@ -146,6 +146,9 @@ def main() -> None:
     )
     args = ap.parse_args()
 
+    from repro.kernels.runtime import use_compile_cache
+
+    use_compile_cache()
     if args.smoke:
         run_smoke()
         run_smoke_search()

@@ -404,6 +404,13 @@ def _worker_main(conn, sid, kind, path, rollback_gen, stopwords, writer_kwargs, 
     # env is inherited through spawn already; the explicit update makes the
     # contract visible and covers vars set after the interpreter started
     os.environ.update(env)
+    # workers are host-only: the unpickling import chain (repro.core ->
+    # search -> jax) has loaded jax, which read JAX_PLATFORMS at import, so
+    # pin the config as well — no accelerator backend can start here and
+    # contend for the chip the coordinator holds
+    import jax
+
+    jax.config.update("jax_platforms", env["JAX_PLATFORMS"])
     fault: Optional[str] = None
     busy = 0.0
     try:
@@ -462,6 +469,7 @@ def _worker_main(conn, sid, kind, path, rollback_gen, stopwords, writer_kwargs, 
             elif op == "stats":
                 s = w.stats()
                 s["busy_s"] = busy
+                s["jax_platforms"] = jax.config.jax_platforms
                 reply = s
             elif op == "poll":
                 if fault == "kill_on_poll":
@@ -496,7 +504,9 @@ def _worker_main(conn, sid, kind, path, rollback_gen, stopwords, writer_kwargs, 
 
 
 def make_worker_directory(kind: str, path: Optional[str]):
-    """Worker-side Directory construction (jax-free import chain)."""
+    """Worker-side Directory construction.  The worker imports jax (via
+    ``repro.core``) but never starts an accelerator backend: ``start`` pins
+    its ``JAX_PLATFORMS`` to ``cpu``."""
     from repro.core.directory import make_directory
 
     return make_directory(kind, path)
@@ -700,12 +710,10 @@ class ProcessBackend(IngestBackend):
     name = "processes"
 
     # the env contract the CI matrix relies on: workers must see the same
-    # filters/flags the coordinator was launched with
+    # filters the coordinator was launched with
     _INHERIT_ENV = (
         "REPRO_KINDS",
         "REPRO_BACKENDS",
-        "REPRO_PALLAS_INTERPRET",
-        "JAX_PLATFORMS",
         "PYTHONPATH",
     )
 
@@ -719,6 +727,9 @@ class ProcessBackend(IngestBackend):
     def start(self, shards, rollback_gens, analyzer, writer_kwargs) -> List[bool]:
         ctx = multiprocessing.get_context("spawn")  # pinned; fork is unsafe
         env = {k: os.environ[k] for k in self._INHERIT_ENV if k in os.environ}
+        # one process holds the chip: shard workers are host-only always,
+        # whatever the coordinator's own JAX_PLATFORMS says
+        env["JAX_PLATFORMS"] = "cpu"
         stopwords = tuple(sorted(analyzer.stopwords))
         for sid in range(self.n_shards):
             parent, child = ctx.Pipe()

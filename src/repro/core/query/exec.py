@@ -15,8 +15,8 @@ Layering (see ARCHITECTURE.md):
 
 The unbatched jitted primitives live here too: they are both the oracle for
 the batched path (exact BM25 + tie-break parity is asserted in tests) and
-the reference semantics for the Pallas TPU kernels in
-``repro.kernels.bm25_topk``.
+the reference semantics for the fused Pallas kernels in
+``repro.kernels.fused_exec``.
 
 Every score is computed by the *same* elementwise expression in both paths
 (the batch kernels are ``jax.vmap`` of the same cores), so batched results

@@ -112,17 +112,19 @@ def _hier_topk(blk_vals, blk_idx, k):
 
 
 @partial(jax.jit, static_argnames=("ps", "k", "use_kernel", "interpret"))
-def _fused_term_all(csr_docs_t, csr_freqs_t, dl_live_t, dl_t, live_t,
-                    starts_t, lengths_t, bases_t, idfs, avgdl, k1, b,
-                    ps, k, use_kernel, interpret):
+def _fused_term_all(csr_docs_t, csr_freqs_t, dl_live_t, starts_t, lengths_t,
+                    bases_t, idfs, avgdl, k1, b, ps, k, use_kernel,
+                    interpret):
     """The whole term group — every segment's gather + score + filter +
     top-k AND the cross-segment merge — as ONE program / one dispatch.
 
-    The jnp selection path scores via the same elementwise ``bm25``
-    expression as ``exec._term_core`` but reads the packed dl|live word (one
-    doc-side gather) and skips the padding mask on gathered doc ids:
-    out-of-row lanes carry arbitrary doc ids but are dead via ``freqs == 0``
-    (score ``-inf``), so they can never surface in a finite result row.
+    Both selection paths read the packed dl|live word with one doc-side
+    gather here in XLA (the kernel takes the gathered words as a tile, so
+    no gather runs in Mosaic).  The jnp path scores via the same
+    elementwise ``bm25`` expression as ``exec._term_core`` and skips the
+    padding mask on gathered doc ids: out-of-row lanes carry arbitrary doc
+    ids but are dead via ``freqs == 0`` (score ``-inf``), so they can never
+    surface in a finite result row.
     """
     per_v, per_i, per_h = [], [], []
     for i, p in enumerate(ps):
@@ -132,11 +134,11 @@ def _fused_term_all(csr_docs_t, csr_freqs_t, dl_live_t, dl_t, live_t,
         )
         inrow = ar < lengths_t[i][:, None]
         freqs = jnp.where(inrow, csr_freqs_t[i][idx], 0)
+        docs = csr_docs_t[i][idx]
+        g = dl_live_t[i][docs]
         if use_kernel:
-            docs = jnp.where(inrow, csr_docs_t[i][idx], 0)
             blk_v, blk_i, blk_c = fk.term_topk_tiles(
-                docs, freqs, dl_t[i], live_t[i], idfs, avgdl, k1, b, k,
-                interpret,
+                g, freqs, idfs, avgdl, k1, b, k, interpret
             )
             vals, pidx = _hier_topk(blk_v, blk_i, k)
             ids = jnp.take_along_axis(
@@ -145,8 +147,6 @@ def _fused_term_all(csr_docs_t, csr_freqs_t, dl_live_t, dl_t, live_t,
             ids = jnp.where(pidx >= 0, ids, -1)
             hits = blk_c.sum(-1)
         else:
-            docs = csr_docs_t[i][idx]
-            g = dl_live_t[i][docs]
             score = bm25(freqs, g >> 1, idfs[:, None], avgdl, k1, b)
             valid = (freqs > 0) & ((g & 1) > 0)
             score = jnp.where(valid, score, -jnp.inf)
@@ -358,7 +358,7 @@ def exec_term_fused(ctx, group: FamilyGroup, k: int) -> List[TopDocs]:
     )
     use_kernel = kernel_enabled(k)
     interpret = resolve_interpret(None)
-    args = ([], [], [], [], [], [], [], [])  # per-seg arg tuples
+    args = ([], [], [], [], [], [])  # per-seg arg tuples
     ps: List[int] = []
     for seg in ctx.segments:
         meta = stage_term_meta(
@@ -366,10 +366,10 @@ def exec_term_fused(ctx, group: FamilyGroup, k: int) -> List[TopDocs]:
         )
         if meta is None:
             continue
-        st, dl, live = _seg_state(ctx, seg, use_kernel)
+        st, _, _ = _seg_state(ctx, seg, use_kernel)
         for lst, v in zip(
             args,
-            (st["csr.docs"], st["csr.freqs"], st["tiled.dl_live"], dl, live,
+            (st["csr.docs"], st["csr.freqs"], st["tiled.dl_live"],
              meta.starts, meta.lengths, np.int32(seg.base_doc)),
         ):
             lst.append(v)

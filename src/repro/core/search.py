@@ -16,8 +16,8 @@ queries is planned into family groups and each group is scored against
 every segment in one dispatch.  ``search`` is a batch of one.  The original
 per-query path survives as ``search_single`` — it is the oracle the batched
 path must match bit-for-bit (same BM25 scores, same ascending-docid
-tie-breaks), and its pure-jnp primitives double as the oracle for the
-Pallas TPU kernel (``repro.kernels.bm25_topk``).
+tie-breaks); it runs the pure-jnp primitives only, never a Pallas kernel,
+so it stays a valid reference for the fused kernels on every backend.
 
 Scoring is Lucene's BM25 (k1=0.9, b=0.4 defaults) with global collection
 statistics.  Postings are padded to power-of-two buckets so segments (and
@@ -303,32 +303,18 @@ class Searcher:
             if n == 0:
                 continue
             st = self._seg_dev(seg)
-            if self.use_pallas:
-                from repro.kernels import ops as kops
-
-                vals, ids, hits = kops.bm25_topk(
-                    jnp.asarray(docs),
-                    jnp.asarray(freqs),
-                    st["doc_lens"],
-                    st["live"],
-                    idf,
-                    self.avgdl,
-                    self.k1,
-                    self.b,
-                    k,
-                )
-            else:
-                vals, ids, hits = _term_topk(
-                    jnp.asarray(docs),
-                    jnp.asarray(freqs),
-                    st["doc_lens"],
-                    st["live"],
-                    idf,
-                    self.avgdl,
-                    self.k1,
-                    self.b,
-                    k,
-                )
+            # the oracle never runs a kernel under test: always the jnp path
+            vals, ids, hits = _term_topk(
+                jnp.asarray(docs),
+                jnp.asarray(freqs),
+                st["doc_lens"],
+                st["live"],
+                idf,
+                self.avgdl,
+                self.k1,
+                self.b,
+                k,
+            )
             total += int(hits)
             per_seg.append(
                 (np.asarray(vals), np.asarray(ids) + seg.base_doc)

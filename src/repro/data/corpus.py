@@ -15,6 +15,7 @@ Deterministic per seed.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, Iterator, Tuple
 
 import numpy as np
@@ -27,9 +28,6 @@ class CorpusConfig:
     zipf_a: float = 1.3
     mean_len: int = 80
     seed: int = 0
-
-
-_WORDS = None
 
 
 def _word(i: int) -> str:
@@ -45,14 +43,22 @@ def _word(i: int) -> str:
     return "w" + "".join(s)
 
 
+@functools.lru_cache(maxsize=4)
+def _vocab_words(vocab: int) -> np.ndarray:
+    """``_word(i)`` for every token id below ``vocab`` (object array, so a
+    document's tokens map to strings with one fancy index)."""
+    return np.array([_word(i) for i in range(vocab)], dtype=object)
+
+
 def synthetic_corpus(cfg: CorpusConfig) -> Iterator[Tuple[Dict, Dict]]:
     """Yields (fields, doc_values) per document."""
     rng = np.random.default_rng(cfg.seed)
+    words = _vocab_words(cfg.vocab)
     for i in range(cfg.n_docs):
         n = max(4, int(rng.lognormal(np.log(cfg.mean_len), 0.5)))
-        toks = rng.zipf(cfg.zipf_a, size=n) % cfg.vocab
-        body = " ".join(_word(t) for t in toks)
-        title = " ".join(_word(t) for t in toks[: max(2, n // 20)])
+        toks = words[rng.zipf(cfg.zipf_a, size=n) % cfg.vocab].tolist()
+        body = " ".join(toks)
+        title = " ".join(toks[: max(2, n // 20)])
         dv = {
             "month": int(rng.integers(0, 12)),
             "dayOfYear": int(rng.integers(0, 365)),

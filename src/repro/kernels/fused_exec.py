@@ -16,9 +16,19 @@ Layout contract (see ARCHITECTURE.md "fused execution"):
   * doc-space tiles: (B, ND_pad) dense arrays, same blocking, ND_pad is the
     segment's doc count padded to a 1024 multiple (padding docs are dead:
     live=0, freqs=0);
-  * per-block winners: (B, NB, 128) vals/idx, entries past k are -inf/-1 —
-    the same output contract as ``bm25_topk.bm25_topk_blocks``;
+  * per-block winners: (B, NB, 128) vals/idx, entries past k are -inf/-1;
   * per-block hit counts ride in lane 0 of a (B, NB, 128) int32 output.
+
+Mosaic legality (every block's trailing two dims are (8, 128) multiples or
+the array's full extent):
+
+  * per-query scalars (idf, range bounds, BM25 params) live in SMEM whole
+    and are read at ``program_id(0)``;
+  * the per-block outputs keep one query's (1, NB, 128) rows resident in
+    VMEM across the block axis (index map ignores it) and each grid step
+    stores its row at ``pl.ds(i, 1)``, so the axis is "arbitrary";
+  * doc-side gathers never run in a kernel (Mosaic has no general gather):
+    the jitted XLA prologue gathers the packed dl|live word per posting.
 
 Selection parity: each block extracts its top-k by k unrolled max/argmax
 steps with a smallest-flat-index tie-break, and flat index order is doc
@@ -34,6 +44,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_ROWS = 8
 BLOCK_COLS = 128
@@ -73,77 +84,92 @@ def _lane0(total):
     return jnp.where(col == 0, total, 0)
 
 
+def _store_block(vals_ref, idx_ref, cnt_ref, vals, idxs, base, hits):
+    """Store block ``program_id(1)``'s winners and hit count into its row
+    of the query-resident (1, NB, 128) outputs; ``base`` offsets the
+    in-block flat index to the flat position in the (B, P) row."""
+    row = pl.ds(pl.program_id(1), 1)
+    vals_ref[0, row, :] = vals
+    idx_ref[0, row, :] = jnp.where(idxs >= 0, idxs + base, -1)
+    cnt_ref[0, row, :] = _lane0(hits)
+
+
+#: SMEM-resident whole array (per-query scalars, BM25 params)
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+#: (1, 8, 128) block of a (B, NB*8, 128) per-query tile array
+_TILE = pl.BlockSpec((1, BLOCK_ROWS, BLOCK_COLS), lambda q, i: (q, i, 0))
+#: (8, 128) block of an (NB*8, 128) doc-space array shared by the batch
+_SHARED_TILE = pl.BlockSpec((BLOCK_ROWS, BLOCK_COLS), lambda q, i: (i, 0))
+
+
+def _blocks_call(kernel, bsz, nb, in_specs, interpret):
+    """``pallas_call`` over grid (B, NB) with the per-block winner outputs:
+    ((B, NB, 128) vals, (B, NB, 128) flat idx, (B, NB, 128) lane-0 hit
+    counts), each query's rows resident across the block axis."""
+    resident = pl.BlockSpec((1, nb, OUT_K), lambda q, i: (q, 0, 0))
+    return pl.pallas_call(
+        kernel,
+        grid=(bsz, nb),
+        in_specs=in_specs,
+        out_specs=[resident, resident, resident],
+        out_shape=[
+            jax.ShapeDtypeStruct((bsz, nb, OUT_K), jnp.float32),
+            jax.ShapeDtypeStruct((bsz, nb, OUT_K), jnp.int32),
+            jax.ShapeDtypeStruct((bsz, nb, BLOCK_COLS), jnp.int32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
+        interpret=interpret,
+    )
+
+
 # ---------------------------------------------------------------------------
 # term: postings traversal + BM25 + live mask + top-k, all in-kernel
 # ---------------------------------------------------------------------------
 
 
-def _term_kernel(params_ref, idf_ref, docs_ref, freqs_ref, dl_ref, live_ref,
-                 vals_ref, idx_ref, cnt_ref, *, k: int):
-    avgdl = params_ref[0, 0]
-    k1 = params_ref[0, 1]
-    b = params_ref[0, 2]
-    idf = idf_ref[0, 0]
+def _term_kernel(params_ref, idf_ref, g_ref, freqs_ref, vals_ref, idx_ref,
+                 cnt_ref, *, k: int):
+    avgdl = params_ref[0]
+    k1 = params_ref[1]
+    b = params_ref[2]
+    idf = idf_ref[pl.program_id(0)]
 
-    docs = docs_ref[0]  # (8,128) postings doc ids for this block
+    g = g_ref[0]  # (8,128) packed (doc_len << 1) | live of each posting
     freqs = freqs_ref[0]
     tf = freqs.astype(jnp.float32)
-    # doc-side gathers stay in VMEM: dl/live are the full (ND_pad,) rows
-    dl = dl_ref[0][docs].astype(jnp.float32)
-    live = live_ref[0][docs] > 0
-    valid = (freqs > 0) & live
+    dl = (g >> 1).astype(jnp.float32)
+    valid = (freqs > 0) & ((g & 1) > 0)
 
     s = idf * (tf * (k1 + 1.0)) / (tf + k1 * (1.0 - b + b * dl / avgdl))
     s = jnp.where(valid, s, -jnp.inf)
 
     vals, idxs = _block_topk(s, k)
     base = pl.program_id(1) * BLOCK  # flat position within this (B,P) row
-    vals_ref[...] = vals.reshape(1, 1, OUT_K)
-    idx_ref[...] = jnp.where(idxs >= 0, idxs + base, -1).reshape(1, 1, OUT_K)
-    cnt_ref[...] = _lane0(jnp.sum(valid.astype(jnp.int32))).reshape(
-        1, 1, BLOCK_COLS
-    )
+    _store_block(vals_ref, idx_ref, cnt_ref, vals, idxs, base,
+                 jnp.sum(valid.astype(jnp.int32)))
 
 
-def term_topk_tiles(docs, freqs, dl, live, idfs, avgdl, k1, b, k, interpret):
-    """docs/freqs: (B, P) gathered postings, P % 1024 == 0; dl/live:
-    (ND_pad,) int32 tiled doc arrays; idfs: (B,).
+def term_topk_tiles(g, freqs, idfs, avgdl, k1, b, k, interpret):
+    """g: (B, P) packed (doc_len << 1) | live of each gathered posting
+    (gathered by the caller's XLA prologue); freqs: (B, P), P % 1024 == 0;
+    idfs: (B,).
 
     Returns per-block winners ((B, NB, 128) vals, (B, NB, 128) flat idx into
     the (B, P) row, (B, NB) hit counts)."""
-    bsz, p = docs.shape
+    bsz, p = freqs.shape
     assert p % BLOCK == 0, p
     nb = p // BLOCK
-    nd = dl.shape[0]
     params = jnp.stack(
-        [jnp.float32(avgdl), jnp.float32(k1), jnp.float32(b), jnp.float32(0)]
-    ).reshape(1, 4)
-    d3 = docs.reshape(bsz, nb * BLOCK_ROWS, BLOCK_COLS)
+        [jnp.float32(avgdl), jnp.float32(k1), jnp.float32(b)]
+    )
+    g3 = g.reshape(bsz, nb * BLOCK_ROWS, BLOCK_COLS)
     f3 = freqs.reshape(bsz, nb * BLOCK_ROWS, BLOCK_COLS)
-    vals, idx, cnt = pl.pallas_call(
-        functools.partial(_term_kernel, k=k),
-        grid=(bsz, nb),
-        in_specs=[
-            pl.BlockSpec((1, 4), lambda q, i: (0, 0)),
-            pl.BlockSpec((1, 1), lambda q, i: (q, 0)),
-            pl.BlockSpec((1, BLOCK_ROWS, BLOCK_COLS), lambda q, i: (q, i, 0)),
-            pl.BlockSpec((1, BLOCK_ROWS, BLOCK_COLS), lambda q, i: (q, i, 0)),
-            pl.BlockSpec((1, nd), lambda q, i: (0, 0)),
-            pl.BlockSpec((1, nd), lambda q, i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, OUT_K), lambda q, i: (q, i, 0)),
-            pl.BlockSpec((1, 1, OUT_K), lambda q, i: (q, i, 0)),
-            pl.BlockSpec((1, 1, BLOCK_COLS), lambda q, i: (q, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bsz, nb, OUT_K), jnp.float32),
-            jax.ShapeDtypeStruct((bsz, nb, OUT_K), jnp.int32),
-            jax.ShapeDtypeStruct((bsz, nb, BLOCK_COLS), jnp.int32),
-        ],
-        interpret=interpret,
-    )(params, idfs.reshape(bsz, 1), d3, f3, dl.reshape(1, nd),
-      live.reshape(1, nd))
+    vals, idx, cnt = _blocks_call(
+        functools.partial(_term_kernel, k=k), bsz, nb,
+        [_SMEM, _SMEM, _TILE, _TILE], interpret,
+    )(params, idfs.astype(jnp.float32), g3, f3)
     return vals, idx, cnt[..., 0]
 
 
@@ -162,11 +188,8 @@ def _bool_kernel(dense_ref, count_ref, live_ref, vals_ref, idx_ref, cnt_ref,
     s = jnp.where(ok, dense, -jnp.inf)
     vals, idxs = _block_topk(s, k)
     base = pl.program_id(1) * BLOCK  # doc-space blocks: flat idx == doc id
-    vals_ref[...] = vals.reshape(1, 1, OUT_K)
-    idx_ref[...] = jnp.where(idxs >= 0, idxs + base, -1).reshape(1, 1, OUT_K)
-    cnt_ref[...] = _lane0(jnp.sum(ok.astype(jnp.int32))).reshape(
-        1, 1, BLOCK_COLS
-    )
+    _store_block(vals_ref, idx_ref, cnt_ref, vals, idxs, base,
+                 jnp.sum(ok.astype(jnp.int32)))
 
 
 def bool_topk_tiles(dense, count, live, k, n_terms, conjunctive, interpret):
@@ -179,27 +202,11 @@ def bool_topk_tiles(dense, count, live, k, n_terms, conjunctive, interpret):
     d3 = dense.reshape(bsz, nb * BLOCK_ROWS, BLOCK_COLS)
     c3 = count.reshape(bsz, nb * BLOCK_ROWS, BLOCK_COLS)
     l3 = live.reshape(nb * BLOCK_ROWS, BLOCK_COLS)
-    vals, idx, cnt = pl.pallas_call(
+    vals, idx, cnt = _blocks_call(
         functools.partial(
             _bool_kernel, k=k, n_terms=n_terms, conjunctive=conjunctive
         ),
-        grid=(bsz, nb),
-        in_specs=[
-            pl.BlockSpec((1, BLOCK_ROWS, BLOCK_COLS), lambda q, i: (q, i, 0)),
-            pl.BlockSpec((1, BLOCK_ROWS, BLOCK_COLS), lambda q, i: (q, i, 0)),
-            pl.BlockSpec((BLOCK_ROWS, BLOCK_COLS), lambda q, i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, OUT_K), lambda q, i: (q, i, 0)),
-            pl.BlockSpec((1, 1, OUT_K), lambda q, i: (q, i, 0)),
-            pl.BlockSpec((1, 1, BLOCK_COLS), lambda q, i: (q, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bsz, nb, OUT_K), jnp.float32),
-            jax.ShapeDtypeStruct((bsz, nb, OUT_K), jnp.int32),
-            jax.ShapeDtypeStruct((bsz, nb, BLOCK_COLS), jnp.int32),
-        ],
-        interpret=interpret,
+        bsz, nb, [_TILE, _TILE, _SHARED_TILE], interpret,
     )(d3, c3, l3)
     return vals, idx, cnt[..., 0]
 
@@ -215,11 +222,8 @@ def _sort_kernel(matched_ref, dv_ref, vals_ref, idx_ref, cnt_ref, *, k: int):
     s = jnp.where(m, dv, -jnp.inf)
     vals, idxs = _block_topk(s, k)
     base = pl.program_id(1) * BLOCK
-    vals_ref[...] = vals.reshape(1, 1, OUT_K)
-    idx_ref[...] = jnp.where(idxs >= 0, idxs + base, -1).reshape(1, 1, OUT_K)
-    cnt_ref[...] = _lane0(jnp.sum(m.astype(jnp.int32))).reshape(
-        1, 1, BLOCK_COLS
-    )
+    _store_block(vals_ref, idx_ref, cnt_ref, vals, idxs, base,
+                 jnp.sum(m.astype(jnp.int32)))
 
 
 def sort_topk_tiles(matched, dv, k, interpret):
@@ -229,24 +233,9 @@ def sort_topk_tiles(matched, dv, k, interpret):
     nb = nd // BLOCK
     m3 = matched.reshape(bsz, nb * BLOCK_ROWS, BLOCK_COLS)
     v3 = dv.reshape(nb * BLOCK_ROWS, BLOCK_COLS)
-    vals, idx, cnt = pl.pallas_call(
-        functools.partial(_sort_kernel, k=k),
-        grid=(bsz, nb),
-        in_specs=[
-            pl.BlockSpec((1, BLOCK_ROWS, BLOCK_COLS), lambda q, i: (q, i, 0)),
-            pl.BlockSpec((BLOCK_ROWS, BLOCK_COLS), lambda q, i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, OUT_K), lambda q, i: (q, i, 0)),
-            pl.BlockSpec((1, 1, OUT_K), lambda q, i: (q, i, 0)),
-            pl.BlockSpec((1, 1, BLOCK_COLS), lambda q, i: (q, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bsz, nb, OUT_K), jnp.float32),
-            jax.ShapeDtypeStruct((bsz, nb, OUT_K), jnp.int32),
-            jax.ShapeDtypeStruct((bsz, nb, BLOCK_COLS), jnp.int32),
-        ],
-        interpret=interpret,
+    vals, idx, cnt = _blocks_call(
+        functools.partial(_sort_kernel, k=k), bsz, nb,
+        [_TILE, _SHARED_TILE], interpret,
     )(m3, v3)
     return vals, idx, cnt[..., 0]
 
@@ -258,8 +247,8 @@ def sort_topk_tiles(matched, dv, k, interpret):
 
 def _range_kernel(lo_ref, hi_ref, dv_ref, live_ref, vals_ref, idx_ref,
                   cnt_ref, *, k: int):
-    lo = lo_ref[0, 0]
-    hi = hi_ref[0, 0]
+    lo = lo_ref[pl.program_id(0)]
+    hi = hi_ref[pl.program_id(0)]
     dv = dv_ref[...]
     live = live_ref[...] > 0
     ok = (dv >= lo) & (dv <= hi) & live
@@ -269,11 +258,8 @@ def _range_kernel(lo_ref, hi_ref, dv_ref, live_ref, vals_ref, idx_ref,
     gid = (base + _flat_iota()).astype(jnp.float32)
     s = jnp.where(ok, -gid, -jnp.inf)
     vals, idxs = _block_topk(s, k)
-    vals_ref[...] = vals.reshape(1, 1, OUT_K)
-    idx_ref[...] = jnp.where(idxs >= 0, idxs + base, -1).reshape(1, 1, OUT_K)
-    cnt_ref[...] = _lane0(jnp.sum(ok.astype(jnp.int32))).reshape(
-        1, 1, BLOCK_COLS
-    )
+    _store_block(vals_ref, idx_ref, cnt_ref, vals, idxs, base,
+                 jnp.sum(ok.astype(jnp.int32)))
 
 
 def range_topk_tiles(dv, live, los, his, k, interpret):
@@ -287,27 +273,10 @@ def range_topk_tiles(dv, live, los, his, k, interpret):
     nb = nd // BLOCK
     v3 = dv.reshape(nb * BLOCK_ROWS, BLOCK_COLS)
     l3 = live.reshape(nb * BLOCK_ROWS, BLOCK_COLS)
-    vals, idx, cnt = pl.pallas_call(
-        functools.partial(_range_kernel, k=k),
-        grid=(bsz, nb),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda q, i: (q, 0)),
-            pl.BlockSpec((1, 1), lambda q, i: (q, 0)),
-            pl.BlockSpec((BLOCK_ROWS, BLOCK_COLS), lambda q, i: (i, 0)),
-            pl.BlockSpec((BLOCK_ROWS, BLOCK_COLS), lambda q, i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, OUT_K), lambda q, i: (q, i, 0)),
-            pl.BlockSpec((1, 1, OUT_K), lambda q, i: (q, i, 0)),
-            pl.BlockSpec((1, 1, BLOCK_COLS), lambda q, i: (q, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bsz, nb, OUT_K), jnp.float32),
-            jax.ShapeDtypeStruct((bsz, nb, OUT_K), jnp.int32),
-            jax.ShapeDtypeStruct((bsz, nb, BLOCK_COLS), jnp.int32),
-        ],
-        interpret=interpret,
-    )(los.reshape(bsz, 1), his.reshape(bsz, 1), v3, l3)
+    vals, idx, cnt = _blocks_call(
+        functools.partial(_range_kernel, k=k), bsz, nb,
+        [_SMEM, _SMEM, _SHARED_TILE, _SHARED_TILE], interpret,
+    )(los, his, v3, l3)
     return vals, idx, cnt[..., 0]
 
 
@@ -324,16 +293,18 @@ def _facet_kernel(matched_ref, bins_ref, hist_ref, cnt_ref, *, n_bins: int):
     m = matched_ref[0] > 0
     # bincount parity: negative bins clip to 0, bins >= n_bins drop
     bins = jnp.maximum(bins_ref[...], 0)
-    ok = m & (bins < n_bins)
-    nbp = hist_ref.shape[-1]
-    onehot = bins[:, :, None] == jax.lax.broadcasted_iota(
-        jnp.int32, (BLOCK_ROWS, BLOCK_COLS, nbp), 2
-    )
-    w = jnp.where(ok, 1.0, 0.0).astype(jnp.float32)
-    contrib = jnp.sum(onehot.astype(jnp.float32) * w[:, :, None], axis=(0, 1))
-    hist_ref[...] += contrib.reshape(1, nbp)
-    cnt_ref[...] = _lane0(jnp.sum(m.astype(jnp.int32))).reshape(
-        1, 1, BLOCK_COLS
+    w = jnp.where(m & (bins < n_bins), 1.0, 0.0).astype(jnp.float32)
+    nbp = hist_ref.shape[1]
+    # per-lane partial histogram (bin on sublanes, doc lane on lanes): each
+    # of the block's 8 rows broadcasts along sublanes against a bin iota,
+    # so no lane<->sublane relayout; the caller reduces the 128 lanes
+    bin_id = jax.lax.broadcasted_iota(jnp.int32, (nbp, BLOCK_COLS), 0)
+    acc = hist_ref[0]
+    for r in range(BLOCK_ROWS):
+        acc = acc + jnp.where(bins[r : r + 1, :] == bin_id, w[r : r + 1, :], 0.0)
+    hist_ref[0] = acc
+    cnt_ref[0, pl.ds(pl.program_id(1), 1), :] = _lane0(
+        jnp.sum(m.astype(jnp.int32))
     )
 
 
@@ -341,32 +312,33 @@ def facet_hist_tiles(matched, bins, n_bins, interpret):
     """matched: (B, ND_pad) int32; bins: (ND_pad,) int32.
 
     Returns ((B, n_bins) float32 counts, (B, NB) per-block match counts).
-    The histogram output block is revisited across the doc grid axis and
-    accumulated in place (``pl.when`` zero-init on the first step); counts
-    are integer-valued float32 sums (< 2^24), so accumulation order cannot
-    change the result vs ``jnp.bincount``.
+    The (1, nbp, 128) per-lane histogram block is revisited across the doc
+    grid axis and accumulated in place (``pl.when`` zero-init on the first
+    step); counts are integer-valued float32 sums (< 2^24), so neither the
+    accumulation order nor the lane reduction can change the result vs
+    ``jnp.bincount``.
     """
     bsz, nd = matched.shape
     assert nd % BLOCK == 0, nd
     nb = nd // BLOCK
-    nbp = -(-n_bins // BLOCK_COLS) * BLOCK_COLS  # pad bins to lane multiple
+    nbp = -(-n_bins // BLOCK_ROWS) * BLOCK_ROWS  # bins on sublanes
     m3 = matched.reshape(bsz, nb * BLOCK_ROWS, BLOCK_COLS)
     b3 = bins.reshape(nb * BLOCK_ROWS, BLOCK_COLS)
     hist, cnt = pl.pallas_call(
         functools.partial(_facet_kernel, n_bins=n_bins),
         grid=(bsz, nb),
-        in_specs=[
-            pl.BlockSpec((1, BLOCK_ROWS, BLOCK_COLS), lambda q, i: (q, i, 0)),
-            pl.BlockSpec((BLOCK_ROWS, BLOCK_COLS), lambda q, i: (i, 0)),
-        ],
+        in_specs=[_TILE, _SHARED_TILE],
         out_specs=[
-            pl.BlockSpec((1, nbp), lambda q, i: (q, 0)),
-            pl.BlockSpec((1, 1, BLOCK_COLS), lambda q, i: (q, i, 0)),
+            pl.BlockSpec((1, nbp, BLOCK_COLS), lambda q, i: (q, 0, 0)),
+            pl.BlockSpec((1, nb, BLOCK_COLS), lambda q, i: (q, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, nbp), jnp.float32),
+            jax.ShapeDtypeStruct((bsz, nbp, BLOCK_COLS), jnp.float32),
             jax.ShapeDtypeStruct((bsz, nb, BLOCK_COLS), jnp.int32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
+        ),
         interpret=interpret,
     )(m3, b3)
-    return hist[:, :n_bins], cnt[..., 0]
+    return hist.sum(-1)[:, :n_bins], cnt[..., 0]

@@ -4,80 +4,16 @@ Each wrapper (a) pads/stages inputs to kernel-friendly tile shapes, (b)
 resolves the execution mode via ``repro.kernels.runtime`` (compiled where a
 Pallas backend exists, interpreted otherwise) so the same call sites run on
 CPU (tests/benches) and compile to Mosaic/Triton on TPU/GPU, and (c)
-performs the cheap XLA epilogues (hierarchical top-k merge, count
-reduction).
+performs the cheap XLA epilogues (count reduction, un-padding).
 """
 
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
 
 from repro.kernels import bitset as _bitset
-from repro.kernels import bm25_topk as _bm25
 from repro.kernels import decode_attn as _decode
 from repro.kernels.runtime import resolve_interpret
-
-
-def _pad_to(x, multiple, value=0):
-    n = x.shape[0]
-    rem = (-n) % multiple
-    if rem == 0:
-        return x
-    return jnp.concatenate([x, jnp.full((rem,) + x.shape[1:], value, x.dtype)])
-
-
-# ---------------------------------------------------------------------------
-
-
-@functools.partial(jax.jit, static_argnames=("k",))
-def _bm25_epilogue(blk_vals, blk_idx, docs, k):
-    flat_v = blk_vals.reshape(-1)
-    flat_i = blk_idx.reshape(-1)
-    vals, pos = jax.lax.top_k(flat_v, k)
-    pidx = flat_i[pos]
-    ids = docs[jnp.clip(pidx, 0, docs.shape[0] - 1)]
-    return vals, jnp.where(pidx >= 0, ids, -1)
-
-
-def bm25_topk(docs, freqs, doc_lens, live, idf, avgdl, k1, b, k=10):
-    """Drop-in for ``search._term_topk`` backed by the Pallas kernel.
-
-    docs/freqs: (P,) padded postings.  Returns (vals, doc_ids, total_hits).
-    """
-    docs = _pad_to(docs, _bm25.BLOCK)
-    freqs = _pad_to(freqs, _bm25.BLOCK)
-    dl = doc_lens[docs]
-    valid = (freqs > 0) & live[docs]
-    kk = min(k, int(docs.shape[0]))
-    blk_vals, blk_idx = _bm25.bm25_topk_blocks(
-        freqs,
-        dl,
-        valid,
-        jnp.float32(idf),
-        jnp.float32(avgdl),
-        jnp.float32(k1),
-        jnp.float32(b),
-        k=kk,
-    )
-    vals, ids = _bm25_epilogue(blk_vals, blk_idx, docs, kk)
-    return vals, ids, valid.sum()
-
-
-def bm25_topk_batch(docs, freqs, doc_lens, live, idfs, avgdl, k1, b, k=10):
-    """Batched executor surface over the fused kernel.
-
-    docs/freqs: (B, P) padded postings, idfs: (B,).  vmap's pallas_call
-    batching rule folds the batch into the kernel grid, so the whole batch
-    is one dispatch per segment — same shape contract as the jnp executor
-    (``repro.core.query.exec._term_topk_batch``): (vals (B, kk),
-    doc_ids (B, kk), hits (B,)).
-    """
-    return jax.vmap(
-        lambda d, f, i: bm25_topk(d, f, doc_lens, live, i, avgdl, k1, b, k)
-    )(jnp.asarray(docs), jnp.asarray(freqs), jnp.asarray(idfs))
 
 
 def bitset_combine(bitmaps, mode="and"):

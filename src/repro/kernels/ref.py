@@ -13,27 +13,6 @@ import jax.numpy as jnp
 
 
 # ---------------------------------------------------------------------------
-# bm25_topk: fused BM25 score + hierarchical top-k over a postings block
-# ---------------------------------------------------------------------------
-
-
-def bm25_scores_ref(freqs, dl, valid, idf, avgdl, k1, b):
-    """BM25 over pre-gathered postings.  freqs/dl/valid: (P,)."""
-    tf = freqs.astype(jnp.float32)
-    dlf = dl.astype(jnp.float32)
-    s = idf * (tf * (k1 + 1.0)) / (tf + k1 * (1.0 - b + b * dlf / avgdl))
-    return jnp.where(valid, s, -jnp.inf)
-
-
-@partial(jax.jit, static_argnames=("k",))
-def bm25_topk_ref(freqs, dl, valid, idf, avgdl, k1, b, k):
-    """Returns (vals (k,), posting_idx (k,)) of the top-k scores."""
-    s = bm25_scores_ref(freqs, dl, valid, idf, avgdl, k1, b)
-    vals, idx = jax.lax.top_k(s, k)
-    return vals, idx
-
-
-# ---------------------------------------------------------------------------
 # bitset: packed-uint32 boolean combine + popcount
 # ---------------------------------------------------------------------------
 

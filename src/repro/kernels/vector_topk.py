@@ -13,6 +13,9 @@ Layout contract (same doc-space tiling as ``fused_exec``):
     components — both are exact no-ops for dot and cosine);
   * doc-space blocks: the doc axis reshapes to (NB*8, 128) and the grid
     walks (B, NB) with (8, 128, D_pad) vector blocks;
+  * query vectors: (B, 1, D_pad), one (1, 1, D_pad) block per query (the
+    unit axis makes the block's trailing dims the array's full extent);
+    hybrid alphas sit in SMEM;
   * per-block winners: (B, NB, 128) vals/idx, entries past k are -inf/-1,
     hit counts in lane 0 of a (B, NB, 128) int32 output — identical to the
     ``fused_exec`` output contract, so the same hierarchical XLA top-k
@@ -29,17 +32,19 @@ from __future__ import annotations
 
 import functools
 
-import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.kernels.fused_exec import (
+    _SMEM,
     BLOCK,
     BLOCK_COLS,
     BLOCK_ROWS,
-    OUT_K,
+    _SHARED_TILE,
+    _TILE,
     _block_topk,
-    _lane0,
+    _blocks_call,
+    _store_block,
 )
 from repro.kernels.runtime import resolve_interpret
 
@@ -71,20 +76,27 @@ def _sims_block(v, q, cosine: bool, dim: int):
     return sims
 
 
+def _query_spec(dp: int):
+    """(1, 1, D_pad) block of the (B, 1, D_pad) query-vector array."""
+    return pl.BlockSpec((1, 1, dp), lambda q, i: (q, 0, 0))
+
+
+def _vmat_spec(dp: int):
+    """(8, 128, D_pad) vector rows of one doc block."""
+    return pl.BlockSpec((BLOCK_ROWS, BLOCK_COLS, dp), lambda q, i: (i, 0, 0))
+
+
 def _vector_kernel(q_ref, vmat_ref, live_ref, vals_ref, idx_ref, cnt_ref,
                    *, k: int, cosine: bool, dim: int):
-    q = q_ref[0]            # (D_pad,)
+    q = q_ref[0, 0]         # (D_pad,)
     v = vmat_ref[...]       # (8, 128, D_pad) vector rows of this doc block
     live = live_ref[...] > 0
     s = jnp.where(live, _sims_block(v, q, cosine, dim), -jnp.inf)
     vals, idxs = _block_topk(s, k)
     base = pl.program_id(1) * BLOCK  # doc-space blocks: flat idx == doc id
-    vals_ref[...] = vals.reshape(1, 1, OUT_K)
-    idx_ref[...] = jnp.where(idxs >= 0, idxs + base, -1).reshape(1, 1, OUT_K)
     # match-all-live semantics: every live doc is a hit
-    cnt_ref[...] = _lane0(jnp.sum(live.astype(jnp.int32))).reshape(
-        1, 1, BLOCK_COLS
-    )
+    _store_block(vals_ref, idx_ref, cnt_ref, vals, idxs, base,
+                 jnp.sum(live.astype(jnp.int32)))
 
 
 def vector_topk_tiles(vmat, live, qvecs, k, cosine=False, dim=None,
@@ -102,28 +114,11 @@ def vector_topk_tiles(vmat, live, qvecs, k, cosine=False, dim=None,
     dim = dp if dim is None else dim
     v3 = vmat.reshape(nb * BLOCK_ROWS, BLOCK_COLS, dp)
     l3 = live.reshape(nb * BLOCK_ROWS, BLOCK_COLS)
-    vals, idx, cnt = pl.pallas_call(
+    vals, idx, cnt = _blocks_call(
         functools.partial(_vector_kernel, k=k, cosine=cosine, dim=dim),
-        grid=(bsz, nb),
-        in_specs=[
-            pl.BlockSpec((1, dp), lambda q, i: (q, 0)),
-            pl.BlockSpec(
-                (BLOCK_ROWS, BLOCK_COLS, dp), lambda q, i: (i, 0, 0)
-            ),
-            pl.BlockSpec((BLOCK_ROWS, BLOCK_COLS), lambda q, i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, OUT_K), lambda q, i: (q, i, 0)),
-            pl.BlockSpec((1, 1, OUT_K), lambda q, i: (q, i, 0)),
-            pl.BlockSpec((1, 1, BLOCK_COLS), lambda q, i: (q, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bsz, nb, OUT_K), jnp.float32),
-            jax.ShapeDtypeStruct((bsz, nb, OUT_K), jnp.int32),
-            jax.ShapeDtypeStruct((bsz, nb, BLOCK_COLS), jnp.int32),
-        ],
-        interpret=interpret,
-    )(qvecs, v3, l3)
+        bsz, nb, [_query_spec(dp), _vmat_spec(dp), _SHARED_TILE],
+        interpret,
+    )(qvecs.reshape(bsz, 1, dp), v3, l3)
     return vals, idx, cnt[..., 0]
 
 
@@ -135,8 +130,8 @@ def vector_topk_tiles(vmat, live, qvecs, k, cosine=False, dim=None,
 def _hybrid_kernel(q_ref, alpha_ref, dense_ref, vmat_ref, live_ref,
                    vals_ref, idx_ref, cnt_ref, *, k: int, cosine: bool,
                    dim: int):
-    q = q_ref[0]
-    alpha = alpha_ref[0, 0]
+    q = q_ref[0, 0]
+    alpha = alpha_ref[pl.program_id(0)]
     dense = dense_ref[0]    # (8, 128) scatter-combined BM25 of this block
     v = vmat_ref[...]
     live = live_ref[...] > 0
@@ -152,11 +147,8 @@ def _hybrid_kernel(q_ref, alpha_ref, dense_ref, vmat_ref, live_ref,
     s = jnp.where(live, s, -jnp.inf)
     vals, idxs = _block_topk(s, k)
     base = pl.program_id(1) * BLOCK
-    vals_ref[...] = vals.reshape(1, 1, OUT_K)
-    idx_ref[...] = jnp.where(idxs >= 0, idxs + base, -1).reshape(1, 1, OUT_K)
-    cnt_ref[...] = _lane0(jnp.sum(live.astype(jnp.int32))).reshape(
-        1, 1, BLOCK_COLS
-    )
+    _store_block(vals_ref, idx_ref, cnt_ref, vals, idxs, base,
+                 jnp.sum(live.astype(jnp.int32)))
 
 
 def hybrid_topk_tiles(dense, vmat, live, qvecs, alphas, k, cosine=False,
@@ -173,28 +165,11 @@ def hybrid_topk_tiles(dense, vmat, live, qvecs, alphas, k, cosine=False,
     d3 = dense.reshape(bsz, nb * BLOCK_ROWS, BLOCK_COLS)
     v3 = vmat.reshape(nb * BLOCK_ROWS, BLOCK_COLS, dp)
     l3 = live.reshape(nb * BLOCK_ROWS, BLOCK_COLS)
-    vals, idx, cnt = pl.pallas_call(
+    vals, idx, cnt = _blocks_call(
         functools.partial(_hybrid_kernel, k=k, cosine=cosine, dim=dim),
-        grid=(bsz, nb),
-        in_specs=[
-            pl.BlockSpec((1, dp), lambda q, i: (q, 0)),
-            pl.BlockSpec((1, 1), lambda q, i: (q, 0)),
-            pl.BlockSpec((1, BLOCK_ROWS, BLOCK_COLS), lambda q, i: (q, i, 0)),
-            pl.BlockSpec(
-                (BLOCK_ROWS, BLOCK_COLS, dp), lambda q, i: (i, 0, 0)
-            ),
-            pl.BlockSpec((BLOCK_ROWS, BLOCK_COLS), lambda q, i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, 1, OUT_K), lambda q, i: (q, i, 0)),
-            pl.BlockSpec((1, 1, OUT_K), lambda q, i: (q, i, 0)),
-            pl.BlockSpec((1, 1, BLOCK_COLS), lambda q, i: (q, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bsz, nb, OUT_K), jnp.float32),
-            jax.ShapeDtypeStruct((bsz, nb, OUT_K), jnp.int32),
-            jax.ShapeDtypeStruct((bsz, nb, BLOCK_COLS), jnp.int32),
-        ],
-        interpret=interpret,
-    )(qvecs, alphas.reshape(bsz, 1), d3, v3, l3)
+        bsz, nb,
+        [_query_spec(dp), _SMEM, _TILE, _vmat_spec(dp),
+         _SHARED_TILE],
+        interpret,
+    )(qvecs.reshape(bsz, 1, dp), alphas.astype(jnp.float32), d3, v3, l3)
     return vals, idx, cnt[..., 0]

@@ -4,43 +4,11 @@ Kernels run with interpret=True on CPU (the Bash-level target is TPU; the
 interpreter executes the same kernel body).
 """
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.kernels.bm25_topk import bm25_topk_blocks, BLOCK
-
-
-@pytest.mark.parametrize("p", [1024, 2048, 8192])
-@pytest.mark.parametrize("k", [1, 10, 64])
-def test_bm25_topk_shapes(rng, p, k):
-    freqs = jnp.asarray(rng.integers(0, 20, p).astype(np.int32))
-    dl = jnp.asarray(rng.integers(10, 500, p).astype(np.float32))
-    valid = jnp.asarray(rng.random(p) > 0.2)
-    args = (freqs, dl, valid, 1.7, 123.0, 0.9, 0.4)
-    blk_v, blk_i = bm25_topk_blocks(*args, k=k, interpret=True)
-    vals, idx = jax.lax.top_k(blk_v.reshape(-1), k)
-    rv, ri = ref.bm25_topk_ref(*args, k)
-    np.testing.assert_allclose(np.asarray(vals), np.asarray(rv), rtol=1e-5)
-    # indices must select the same score multiset
-    got = blk_i.reshape(-1)[np.asarray(idx)]
-    s = ref.bm25_scores_ref(*args)
-    np.testing.assert_allclose(
-        np.asarray(s)[np.asarray(got)], np.asarray(rv), rtol=1e-5
-    )
-
-
-def test_bm25_topk_all_invalid(rng):
-    p = BLOCK
-    freqs = jnp.zeros(p, jnp.int32)
-    dl = jnp.ones(p, jnp.float32)
-    valid = jnp.zeros(p, bool)
-    blk_v, blk_i = bm25_topk_blocks(
-        freqs, dl, valid, 1.0, 10.0, 0.9, 0.4, k=5, interpret=True
-    )
-    assert not np.isfinite(np.asarray(blk_v)[:, :5]).any()
 
 
 @pytest.mark.parametrize("t", [1, 2, 4, 7])
@@ -93,3 +61,51 @@ def test_decode_attn_matches_model_path(rng):
     np.testing.assert_allclose(
         np.asarray(model_out), np.asarray(kern_out), rtol=2e-5, atol=2e-5
     )
+
+
+def _run_py(code, env):
+    import os
+    import subprocess
+    import sys
+
+    full = {k: v for k, v in os.environ.items()
+            if k != "JAX_COMPILATION_CACHE_DIR"}
+    full.update(env)
+    out = subprocess.run(
+        [sys.executable, "-c", code], env=full, capture_output=True,
+        text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr
+    return out.stdout.strip().splitlines()[-1]
+
+
+def test_compile_cache_env_dir_is_left_to_jax(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, the helper sets nothing and the
+    compiled programs land in that directory."""
+    code = (
+        "import jax, jax.numpy as jnp\n"
+        "from repro.kernels.runtime import use_compile_cache\n"
+        "d = use_compile_cache()\n"
+        "jax.jit(lambda x: x * 2 + 1)(jnp.ones(3)).block_until_ready()\n"
+        "print(d, jax.config.jax_compilation_cache_dir)\n"
+    )
+    got = _run_py(code, {
+        "JAX_COMPILATION_CACHE_DIR": str(tmp_path),
+        "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
+    })
+    assert got == f"{tmp_path} {tmp_path}"
+    assert any(tmp_path.iterdir())
+
+
+def test_compile_cache_defaults_to_fixed_checkout_dir():
+    """Unset, the cache is the checkout's own fixed .jax_cache directory."""
+    from pathlib import Path
+
+    code = (
+        "import jax\n"
+        "from repro.kernels.runtime import use_compile_cache\n"
+        "d = use_compile_cache()\n"
+        "print(d, jax.config.jax_compilation_cache_dir)\n"
+    )
+    want = str(Path(__file__).resolve().parents[1] / ".jax_cache")
+    assert _run_py(code, {}) == f"{want} {want}"

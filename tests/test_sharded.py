@@ -572,3 +572,16 @@ def test_worker_sigkill_between_commit_phases_rolls_back_wave(
     finally:
         rec.close()
         ref.close()
+
+
+@pytest.mark.parametrize("backend", ["processes"])
+def test_process_workers_are_host_only(backend, monkeypatch):
+    """One process holds the chip: shard workers run with JAX_PLATFORMS=cpu
+    even when the coordinator's own environment does not set it."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    eng = ShardedEngine("ram", n_shards=2, backend=backend)
+    try:
+        per_shard = eng.writer.stats()["per_shard"]
+        assert [s["jax_platforms"] for s in per_shard] == ["cpu", "cpu"]
+    finally:
+        eng.close()
