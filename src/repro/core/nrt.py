@@ -34,6 +34,7 @@ import time
 from typing import Optional
 
 from repro.core.lifecycle import SegmentInfos
+from repro.core.query import profile
 from repro.core.query.cache import SegmentDeviceCache
 from repro.core.search import Searcher
 from repro.core.writer import IndexWriter
@@ -55,9 +56,11 @@ class SearcherManager:
         writer: IndexWriter,
         use_pallas: bool = False,
         device_cache: Optional[SegmentDeviceCache] = None,
+        shard: Optional[int] = None,
     ) -> None:
         self.writer = writer
         self.use_pallas = use_pallas
+        self.shard = shard  # the shard this manager serves (span attr)
         # explicit None check: an empty cache is falsy (it has __len__)
         self.device_cache = (
             device_cache
@@ -68,7 +71,6 @@ class SearcherManager:
         self._searcher: Optional[Searcher] = None
         self._live = None  # LiveSnapshot the current searcher holds
         self._live_token: Optional[int] = None
-        self.reopen_times: list = []
         self.maybe_reopen(force_flush=False)
 
     @property
@@ -99,9 +101,15 @@ class SearcherManager:
         ingest) or the live mirror degraded — visibility semantics are
         identical either way.
 
-        Returns the reopen latency in seconds (the paper's Fig 4b metric).
+        Returns the reopen latency in seconds (the paper's Fig 4b metric),
+        also recorded as the ``nrt.reopen`` span.
         """
         t0 = time.perf_counter()
+        with profile.span("nrt.reopen", shard=self.shard):
+            self._reopen(force_flush)
+        return time.perf_counter() - t0
+
+    def _reopen(self, force_flush: bool) -> None:
         live = None
         if self.writer.buffered_docs:
             if force_flush:
@@ -132,6 +140,3 @@ class SearcherManager:
             self._infos = infos
             self._live = live
             self._live_token = live_token
-        dt = time.perf_counter() - t0
-        self.reopen_times.append(dt)
-        return dt

@@ -335,9 +335,10 @@ def _finalize_scored(
 ) -> List[TopDocs]:
     """Trim -inf padding and box per-query TopDocs (rows beyond ``n`` are
     batch padding)."""
-    vals_h = np.asarray(vals)
-    ids_h = np.asarray(ids)
-    totals_h = np.asarray(totals)
+    with profile.span("exec.fetch"):
+        vals_h = np.asarray(vals)
+        ids_h = np.asarray(ids)
+        totals_h = np.asarray(totals)
     out = []
     for i in range(n):
         m = np.isfinite(vals_h[i])
@@ -377,12 +378,13 @@ def _merge_segment_candidates(
 ) -> List[TopDocs]:
     if not per_seg:
         return [empty_topdocs() for _ in range(n)]
-    vals, ids, totals = _concat_merge(
-        tuple(v for v, _, _ in per_seg),
-        tuple(i for _, i, _ in per_seg),
-        tuple(h for _, _, h in per_seg),
-        k=k,
-    )
+    with profile.span("exec.dispatch", program="_concat_merge"):
+        vals, ids, totals = _concat_merge(
+            tuple(v for v, _, _ in per_seg),
+            tuple(i for _, i, _ in per_seg),
+            tuple(h for _, _, h in per_seg),
+            k=k,
+        )
     return _finalize_scored(vals, ids, totals, n)
 
 
@@ -398,29 +400,33 @@ def _exec_term(ctx, group: FamilyGroup, k: int) -> List[TopDocs]:
         return fused.exec_term_fused(ctx, group, k)
     n = len(group.queries)
     pad = bucket_batch(n) - n
-    idfs = np.asarray(
-        [ctx.idf(q) for q in group.queries] + [0.0] * pad, dtype=np.float32
-    )
-    idfs_dev = jnp.asarray(idfs)  # batch-constant: upload once, not per seg
+    with profile.span("exec.stage"):
+        idfs = np.asarray(
+            [ctx.idf(q) for q in group.queries] + [0.0] * pad,
+            dtype=np.float32,
+        )
+        idfs_dev = jnp.asarray(idfs)  # batch-constant: upload once
     per_seg = []
     for seg in ctx.segments:
-        staged = stage_term_postings(seg, group.queries, pad_rows=pad)
+        with profile.span("exec.stage"):
+            staged = stage_term_postings(seg, group.queries, pad_rows=pad)
         if staged is None:
             continue
         docs, freqs = staged
         st = ctx._seg_dev(seg)
-        vals, ids, hits = _term_topk_batch(
-            jnp.asarray(docs),
-            jnp.asarray(freqs),
-            st["doc_lens"],
-            st["live"],
-            idfs_dev,
-            ctx.avgdl,
-            ctx.k1,
-            ctx.b,
-            k,
-        )
-        profile.record("vmap.term")
+        with profile.span("exec.dispatch", program="_term_topk_batch"):
+            vals, ids, hits = _term_topk_batch(
+                jnp.asarray(docs),
+                jnp.asarray(freqs),
+                st["doc_lens"],
+                st["live"],
+                idfs_dev,
+                ctx.avgdl,
+                ctx.k1,
+                ctx.b,
+                k,
+            )
+            profile.record("vmap.term")
         per_seg.append((vals, ids + seg.base_doc, hits))
     return _merge_segment_candidates(per_seg, n, k)
 
@@ -434,31 +440,34 @@ def _exec_bool(ctx, group: FamilyGroup, k: int) -> List[TopDocs]:
     pad = bucket_batch(n) - n
     mode, n_terms = group.key[1], group.key[2]
     conj = mode == "and"
-    idfs = np.zeros((n + pad, n_terms), dtype=np.float32)
-    for i, q in enumerate(group.queries):
-        idfs[i] = [ctx.idf(t) for t in q.terms]
-    idfs_dev = jnp.asarray(idfs)
+    with profile.span("exec.stage"):
+        idfs = np.zeros((n + pad, n_terms), dtype=np.float32)
+        for i, q in enumerate(group.queries):
+            idfs[i] = [ctx.idf(t) for t in q.terms]
+        idfs_dev = jnp.asarray(idfs)
     per_seg = []
     for seg in ctx.segments:
-        staged = stage_bool_postings(seg, group.queries, pad_rows=pad)
+        with profile.span("exec.stage"):
+            staged = stage_bool_postings(seg, group.queries, pad_rows=pad)
         if staged is None:
             continue
         docs, freqs = staged
         st = ctx._seg_dev(seg)
-        vals, ids, hits = _bool_topk_batch(
-            jnp.asarray(docs),
-            jnp.asarray(freqs),
-            idfs_dev,
-            st["doc_lens"],
-            st["live"],
-            ctx.avgdl,
-            ctx.k1,
-            ctx.b,
-            k,
-            conj,
-            n_terms,
-        )
-        profile.record("vmap.bool")
+        with profile.span("exec.dispatch", program="_bool_topk_batch"):
+            vals, ids, hits = _bool_topk_batch(
+                jnp.asarray(docs),
+                jnp.asarray(freqs),
+                idfs_dev,
+                st["doc_lens"],
+                st["live"],
+                ctx.avgdl,
+                ctx.k1,
+                ctx.b,
+                k,
+                conj,
+                n_terms,
+            )
+            profile.record("vmap.bool")
         per_seg.append((vals, ids + seg.base_doc, hits))
     return _merge_segment_candidates(per_seg, n, k)
 
@@ -474,19 +483,21 @@ def _exec_sort(ctx, group: FamilyGroup, k: int) -> List[TopDocs]:
     terms = [q.term for q in group.queries]
     per_seg = []
     for seg in ctx.segments:
-        staged = stage_term_postings(seg, terms, pad_rows=pad)
+        with profile.span("exec.stage"):
+            staged = stage_term_postings(seg, terms, pad_rows=pad)
         if staged is None:
             continue
         docs, freqs = staged
         st = ctx._seg_dev(seg)
-        vals, ids, hits = _sort_topk_batch(
-            jnp.asarray(docs),
-            jnp.asarray(freqs),
-            st[f"dv.{dv_field}"],
-            st["live"],
-            k,
-        )
-        profile.record("vmap.sort")
+        with profile.span("exec.dispatch", program="_sort_topk_batch"):
+            vals, ids, hits = _sort_topk_batch(
+                jnp.asarray(docs),
+                jnp.asarray(freqs),
+                st[f"dv.{dv_field}"],
+                st["live"],
+                k,
+            )
+            profile.record("vmap.sort")
         per_seg.append((vals, ids + seg.base_doc, hits))
     return _merge_segment_candidates(per_seg, n, k)
 
@@ -499,23 +510,25 @@ def _exec_range(ctx, group: FamilyGroup, k: int) -> List[TopDocs]:
     n = len(group.queries)
     pad = bucket_batch(n) - n
     dv_field = group.key[1]
-    los = jnp.asarray(
-        [q.lo for q in group.queries] + [0] * pad, dtype=jnp.int32
-    )
-    his = jnp.asarray(
-        [q.hi for q in group.queries] + [-1] * pad, dtype=jnp.int32
-    )
+    with profile.span("exec.stage"):
+        los = jnp.asarray(
+            [q.lo for q in group.queries] + [0] * pad, dtype=jnp.int32
+        )
+        his = jnp.asarray(
+            [q.hi for q in group.queries] + [-1] * pad, dtype=jnp.int32
+        )
     per_seg = []
     for seg in ctx.segments:
         st = ctx._seg_dev(seg)
-        vals, ids, hits = _range_topk_batch(
-            st[f"dv.{dv_field}"],
-            st["live"],
-            los,
-            his,
-            k,
-        )
-        profile.record("vmap.range")
+        with profile.span("exec.dispatch", program="_range_topk_batch"):
+            vals, ids, hits = _range_topk_batch(
+                st[f"dv.{dv_field}"],
+                st["live"],
+                los,
+                his,
+                k,
+            )
+            profile.record("vmap.range")
         per_seg.append((vals, ids + seg.base_doc, hits))
     return _merge_segment_candidates(per_seg, n, k)
 
@@ -534,31 +547,36 @@ def _exec_facet(ctx, group: FamilyGroup, k: int) -> List[TopDocs]:
         dv_bins = st[f"dv.{dv_field}"].astype(jnp.int32)
         if match_all:
             # identical per query: one dispatch, replicated host-side
-            c = np.asarray(
-                _facet_counts(st["live"], dv_bins, n_bins), dtype=np.float64
-            )
-            t = int(np.asarray(st["live"].sum()))
-            profile.record("vmap.facet")
+            with profile.span("exec.dispatch", program="_facet_counts"):
+                c_dev = _facet_counts(st["live"], dv_bins, n_bins)
+                t_dev = st["live"].sum()
+                profile.record("vmap.facet")
+            with profile.span("exec.fetch"):
+                c = np.asarray(c_dev, dtype=np.float64)
+                t = int(np.asarray(t_dev))
             counts += c[None, :]
             totals += t
         else:
             pad = bucket_batch(n) - n
-            staged = stage_term_postings(
-                seg, [q.term for q in group.queries], pad_rows=pad
-            )
+            with profile.span("exec.stage"):
+                staged = stage_term_postings(
+                    seg, [q.term for q in group.queries], pad_rows=pad
+                )
             if staged is None:
                 continue
             docs, freqs = staged
-            c, t = _facet_batch(
-                jnp.asarray(docs),
-                jnp.asarray(freqs),
-                st["live"],
-                dv_bins,
-                n_bins,
-            )
-            profile.record("vmap.facet")
-            counts += np.asarray(c, dtype=np.float64)[:n]
-            totals += np.asarray(t, dtype=np.int64)[:n]
+            with profile.span("exec.dispatch", program="_facet_batch"):
+                c, t = _facet_batch(
+                    jnp.asarray(docs),
+                    jnp.asarray(freqs),
+                    st["live"],
+                    dv_bins,
+                    n_bins,
+                )
+                profile.record("vmap.facet")
+            with profile.span("exec.fetch"):
+                counts += np.asarray(c, dtype=np.float64)[:n]
+                totals += np.asarray(t, dtype=np.int64)[:n]
     out = []
     for i, q in enumerate(group.queries):
         order = np.argsort(-counts[i], kind="stable")[:k]
@@ -574,6 +592,11 @@ def _exec_facet(ctx, group: FamilyGroup, k: int) -> List[TopDocs]:
 
 
 def _exec_phrase(ctx, group: FamilyGroup, k: int) -> List[TopDocs]:
+    with profile.span("exec.phrase", n=len(group.queries)):
+        return _score_phrases(ctx, group, k)
+
+
+def _score_phrases(ctx, group: FamilyGroup, k: int) -> List[TopDocs]:
     """Batched exact-phrase scorer: one vectorized pass per segment.
 
     Phrase verification is inherently a host-side positions merge (Lucene's
@@ -733,18 +756,22 @@ def _exec_vector(ctx, group: FamilyGroup, k: int) -> List[TopDocs]:
     pad = bucket_batch(n) - n
     dim, metric = group.key[1], group.key[2]
     cosine = metric == "cosine"
-    qvecs = np.zeros((n + pad, dim), dtype=np.float32)
-    for i, q in enumerate(group.queries):
-        qvecs[i] = q.vector
-    qdev = jnp.asarray(qvecs)
+    with profile.span("exec.stage"):
+        qvecs = np.zeros((n + pad, dim), dtype=np.float32)
+        for i, q in enumerate(group.queries):
+            qvecs[i] = q.vector
+        qdev = jnp.asarray(qvecs)
     per_seg = []
     for seg in ctx.segments:
         vmat = _seg_vector(ctx, seg)
         if vmat is None:
             continue
         st = ctx._seg_dev(seg)
-        vals, ids, hits = _vector_topk_batch(vmat, st["live"], qdev, k, cosine)
-        profile.record("vmap.vector")
+        with profile.span("exec.dispatch", program="_vector_topk_batch"):
+            vals, ids, hits = _vector_topk_batch(
+                vmat, st["live"], qdev, k, cosine
+            )
+            profile.record("vmap.vector")
         per_seg.append((vals, ids + seg.base_doc, hits))
     return _merge_segment_candidates(per_seg, n, k)
 
@@ -760,25 +787,27 @@ def _exec_hybrid(ctx, group: FamilyGroup, k: int) -> List[TopDocs]:
     dim, metric = group.key[1], group.key[2]
     cosine = metric == "cosine"
     terms = [q.term for q in group.queries]
-    qvecs = np.zeros((n + pad, dim), dtype=np.float32)
-    for i, q in enumerate(group.queries):
-        qvecs[i] = q.vector.vector
-    idfs = np.asarray(
-        [ctx.idf(t) for t in terms] + [0.0] * pad, dtype=np.float32
-    )
-    alphas = np.asarray(
-        [q.alpha for q in group.queries] + [0.0] * pad, dtype=np.float32
-    )
-    qdev = jnp.asarray(qvecs)
-    idfs_dev = jnp.asarray(idfs)
-    alphas_dev = jnp.asarray(alphas)
+    with profile.span("exec.stage"):
+        qvecs = np.zeros((n + pad, dim), dtype=np.float32)
+        for i, q in enumerate(group.queries):
+            qvecs[i] = q.vector.vector
+        idfs = np.asarray(
+            [ctx.idf(t) for t in terms] + [0.0] * pad, dtype=np.float32
+        )
+        alphas = np.asarray(
+            [q.alpha for q in group.queries] + [0.0] * pad, dtype=np.float32
+        )
+        qdev = jnp.asarray(qvecs)
+        idfs_dev = jnp.asarray(idfs)
+        alphas_dev = jnp.asarray(alphas)
     per_seg = []
     for seg in ctx.segments:
         vmat = _seg_vector(ctx, seg)
         if vmat is None:
             continue
         st = ctx._seg_dev(seg)
-        staged = stage_term_postings(seg, terms, pad_rows=pad)
+        with profile.span("exec.stage"):
+            staged = stage_term_postings(seg, terms, pad_rows=pad)
         if staged is None:
             # match-all-live semantics: no term postings here, but the
             # vector half still scores every live doc (BM25 sum = 0)
@@ -786,22 +815,23 @@ def _exec_hybrid(ctx, group: FamilyGroup, k: int) -> List[TopDocs]:
             freqs = np.zeros((n + pad, 8), dtype=np.int32)
         else:
             docs, freqs = staged
-        vals, ids, hits = _hybrid_topk_batch(
-            jnp.asarray(docs),
-            jnp.asarray(freqs),
-            st["doc_lens"],
-            vmat,
-            st["live"],
-            qdev,
-            idfs_dev,
-            ctx.avgdl,
-            ctx.k1,
-            ctx.b,
-            alphas_dev,
-            k,
-            cosine,
-        )
-        profile.record("vmap.hybrid")
+        with profile.span("exec.dispatch", program="_hybrid_topk_batch"):
+            vals, ids, hits = _hybrid_topk_batch(
+                jnp.asarray(docs),
+                jnp.asarray(freqs),
+                st["doc_lens"],
+                vmat,
+                st["live"],
+                qdev,
+                idfs_dev,
+                ctx.avgdl,
+                ctx.k1,
+                ctx.b,
+                alphas_dev,
+                k,
+                cosine,
+            )
+            profile.record("vmap.hybrid")
         per_seg.append((vals, ids + seg.base_doc, hits))
     return _merge_segment_candidates(per_seg, n, k)
 

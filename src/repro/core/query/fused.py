@@ -351,36 +351,39 @@ def _seg_state(ctx, seg, use_kernel):
 def exec_term_fused(ctx, group: FamilyGroup, k: int) -> List[TopDocs]:
     n = len(group.queries)
     pad = bucket_batch(n) - n
-    # metadata stays numpy: the pjit C++ dispatch converts (B,)-sized args
-    # far cheaper than a Python-level device_put per segment
-    idfs = np.asarray(
-        [ctx.idf(q) for q in group.queries] + [0.0] * pad, dtype=np.float32
-    )
     use_kernel = kernel_enabled(k)
     interpret = resolve_interpret(None)
     args = ([], [], [], [], [], [])  # per-seg arg tuples
     ps: List[int] = []
-    for seg in ctx.segments:
-        meta = stage_term_meta(
-            seg, group.queries, pad_rows=pad, tile=use_kernel
+    with profile.span("exec.stage"):
+        # metadata stays numpy: the pjit C++ dispatch converts (B,)-sized
+        # args far cheaper than a Python-level device_put per segment
+        idfs = np.asarray(
+            [ctx.idf(q) for q in group.queries] + [0.0] * pad,
+            dtype=np.float32,
         )
-        if meta is None:
-            continue
-        st, _, _ = _seg_state(ctx, seg, use_kernel)
-        for lst, v in zip(
-            args,
-            (st["csr.docs"], st["csr.freqs"], st["tiled.dl_live"],
-             meta.starts, meta.lengths, np.int32(seg.base_doc)),
-        ):
-            lst.append(v)
-        ps.append(meta.p)
+        for seg in ctx.segments:
+            meta = stage_term_meta(
+                seg, group.queries, pad_rows=pad, tile=use_kernel
+            )
+            if meta is None:
+                continue
+            st, _, _ = _seg_state(ctx, seg, use_kernel)
+            for lst, v in zip(
+                args,
+                (st["csr.docs"], st["csr.freqs"], st["tiled.dl_live"],
+                 meta.starts, meta.lengths, np.int32(seg.base_doc)),
+            ):
+                lst.append(v)
+            ps.append(meta.p)
     if not ps:
         return _merge_segment_candidates([], n, k)
-    vals, ids, totals = _fused_term_all(
-        *(tuple(a) for a in args), idfs, ctx.avgdl, ctx.k1, ctx.b,
-        ps=tuple(ps), k=k, use_kernel=use_kernel, interpret=interpret,
-    )
-    profile.record("fused.term")  # the whole group: ONE dispatch
+    with profile.span("exec.dispatch", program="_fused_term_all"):
+        vals, ids, totals = _fused_term_all(
+            *(tuple(a) for a in args), idfs, ctx.avgdl, ctx.k1, ctx.b,
+            ps=tuple(ps), k=k, use_kernel=use_kernel, interpret=interpret,
+        )
+        profile.record("fused.term")  # the whole group: ONE dispatch
     return _finalize_scored(vals, ids, totals, n)
 
 
@@ -389,27 +392,30 @@ def exec_bool_fused(ctx, group: FamilyGroup, k: int) -> List[TopDocs]:
     pad = bucket_batch(n) - n
     mode, n_terms = group.key[1], group.key[2]
     conj = mode == "and"
-    idfs = np.zeros((n + pad, n_terms), dtype=np.float32)
-    for i, q in enumerate(group.queries):
-        idfs[i] = [ctx.idf(t) for t in q.terms]
+    with profile.span("exec.stage"):
+        idfs = np.zeros((n + pad, n_terms), dtype=np.float32)
+        for i, q in enumerate(group.queries):
+            idfs[i] = [ctx.idf(t) for t in q.terms]
     use_kernel = kernel_enabled(k)
     interpret = resolve_interpret(None)
     per_seg = []
     for seg in ctx.segments:
-        meta = stage_bool_meta(
-            seg, group.queries, pad_rows=pad, tile=use_kernel
-        )
+        with profile.span("exec.stage"):
+            meta = stage_bool_meta(
+                seg, group.queries, pad_rows=pad, tile=use_kernel
+            )
         if meta is None:
             continue
         st, dl, live = _seg_state(ctx, seg, use_kernel)
-        vals, ids, hits = _fused_bool(
-            st["csr.docs"], st["csr.freqs"], dl, live,
-            meta.starts, meta.lengths, idfs,
-            ctx.avgdl, ctx.k1, ctx.b, seg.base_doc,
-            p=meta.p, k=k, n_terms=n_terms, conjunctive=conj,
-            use_kernel=use_kernel, interpret=interpret,
-        )
-        profile.record("fused.bool")
+        with profile.span("exec.dispatch", program="_fused_bool"):
+            vals, ids, hits = _fused_bool(
+                st["csr.docs"], st["csr.freqs"], dl, live,
+                meta.starts, meta.lengths, idfs,
+                ctx.avgdl, ctx.k1, ctx.b, seg.base_doc,
+                p=meta.p, k=k, n_terms=n_terms, conjunctive=conj,
+                use_kernel=use_kernel, interpret=interpret,
+            )
+            profile.record("fused.bool")
         per_seg.append((vals, ids, hits))
     return _merge_segment_candidates(per_seg, n, k)
 
@@ -423,17 +429,19 @@ def exec_sort_fused(ctx, group: FamilyGroup, k: int) -> List[TopDocs]:
     interpret = resolve_interpret(None)
     per_seg = []
     for seg in ctx.segments:
-        meta = stage_term_meta(seg, terms, pad_rows=pad, tile=use_kernel)
+        with profile.span("exec.stage"):
+            meta = stage_term_meta(seg, terms, pad_rows=pad, tile=use_kernel)
         if meta is None:
             continue
         st, _, live = _seg_state(ctx, seg, use_kernel)
         dv = st[f"tiled.dv.{dv_field}" if use_kernel else f"dv.{dv_field}"]
-        vals, ids, hits = _fused_sort(
-            st["csr.docs"], st["csr.freqs"], dv, live,
-            meta.starts, meta.lengths, seg.base_doc,
-            p=meta.p, k=k, use_kernel=use_kernel, interpret=interpret,
-        )
-        profile.record("fused.sort")
+        with profile.span("exec.dispatch", program="_fused_sort"):
+            vals, ids, hits = _fused_sort(
+                st["csr.docs"], st["csr.freqs"], dv, live,
+                meta.starts, meta.lengths, seg.base_doc,
+                p=meta.p, k=k, use_kernel=use_kernel, interpret=interpret,
+            )
+            profile.record("fused.sort")
         per_seg.append((vals, ids, hits))
     return _merge_segment_candidates(per_seg, n, k)
 
@@ -442,23 +450,25 @@ def exec_range_fused(ctx, group: FamilyGroup, k: int) -> List[TopDocs]:
     n = len(group.queries)
     pad = bucket_batch(n) - n
     dv_field = group.key[1]
-    los = np.asarray(
-        [q.lo for q in group.queries] + [0] * pad, dtype=np.int32
-    )
-    his = np.asarray(
-        [q.hi for q in group.queries] + [-1] * pad, dtype=np.int32
-    )
+    with profile.span("exec.stage"):
+        los = np.asarray(
+            [q.lo for q in group.queries] + [0] * pad, dtype=np.int32
+        )
+        his = np.asarray(
+            [q.hi for q in group.queries] + [-1] * pad, dtype=np.int32
+        )
     use_kernel = kernel_enabled(k)
     interpret = resolve_interpret(None)
     per_seg = []
     for seg in ctx.segments:
         st, _, live = _seg_state(ctx, seg, use_kernel)
         dv = st[f"tiled.dv.{dv_field}" if use_kernel else f"dv.{dv_field}"]
-        vals, ids, hits = _fused_range(
-            dv, live, los, his, seg.base_doc,
-            k=k, use_kernel=use_kernel, interpret=interpret,
-        )
-        profile.record("fused.range")
+        with profile.span("exec.dispatch", program="_fused_range"):
+            vals, ids, hits = _fused_range(
+                dv, live, los, his, seg.base_doc,
+                k=k, use_kernel=use_kernel, interpret=interpret,
+            )
+            profile.record("fused.range")
         per_seg.append((vals, ids, hits))
     return _merge_segment_candidates(per_seg, n, k)
 
@@ -480,12 +490,13 @@ def exec_facet_fused(ctx, group: FamilyGroup, k: int) -> List[TopDocs]:
             p = TILE
         else:
             pad = bucket_batch(n) - n
-            meta = stage_term_meta(
-                seg,
-                [q.term for q in group.queries],
-                pad_rows=pad,
-                tile=use_kernel,
-            )
+            with profile.span("exec.stage"):
+                meta = stage_term_meta(
+                    seg,
+                    [q.term for q in group.queries],
+                    pad_rows=pad,
+                    tile=use_kernel,
+                )
             if meta is None:
                 continue
             starts = meta.starts
@@ -493,20 +504,22 @@ def exec_facet_fused(ctx, group: FamilyGroup, k: int) -> List[TopDocs]:
             p = meta.p
         st, _, live = _seg_state(ctx, seg, use_kernel)
         dv = st[f"tiled.dv.{dv_field}" if use_kernel else f"dv.{dv_field}"]
-        c, t = _fused_facet(
-            st["csr.docs"], st["csr.freqs"], live, dv, starts, lengths,
-            p=p, n_bins=n_bins, match_all=match_all,
-            use_kernel=use_kernel, interpret=interpret,
-        )
-        profile.record("fused.facet")
+        with profile.span("exec.dispatch", program="_fused_facet"):
+            c, t = _fused_facet(
+                st["csr.docs"], st["csr.freqs"], live, dv, starts, lengths,
+                p=p, n_bins=n_bins, match_all=match_all,
+                use_kernel=use_kernel, interpret=interpret,
+            )
+            profile.record("fused.facet")
         counts_dev = c if counts_dev is None else counts_dev + c
         totals_dev = t if totals_dev is None else totals_dev + t
     if counts_dev is None:
         counts = np.zeros((n, n_bins), dtype=np.float64)
         totals = np.zeros(n, dtype=np.int64)
     else:
-        counts = np.asarray(counts_dev, dtype=np.float64)
-        totals = np.asarray(totals_dev, dtype=np.int64)
+        with profile.span("exec.fetch"):
+            counts = np.asarray(counts_dev, dtype=np.float64)
+            totals = np.asarray(totals_dev, dtype=np.int64)
         if match_all:  # identical per query: replicate the single row
             counts = np.repeat(counts, n, axis=0)
             totals = np.repeat(totals, n)
@@ -544,9 +557,10 @@ def exec_vector_fused(ctx, group: FamilyGroup, k: int) -> List[TopDocs]:
     cosine = metric == "cosine"
     use_kernel = kernel_enabled(k)
     interpret = resolve_interpret(None)
-    qvecs = _vector_group_inputs(group, pad, dim, use_kernel)
-    for i, q in enumerate(group.queries):
-        qvecs[i, :dim] = q.vector
+    with profile.span("exec.stage"):
+        qvecs = _vector_group_inputs(group, pad, dim, use_kernel)
+        for i, q in enumerate(group.queries):
+            qvecs[i, :dim] = q.vector
     per_seg = []
     for seg in ctx.segments:
         if VECTOR_FIELD not in seg.doc_values:
@@ -555,12 +569,13 @@ def exec_vector_fused(ctx, group: FamilyGroup, k: int) -> List[TopDocs]:
         vmat = st[
             f"tiled.dv.{VECTOR_FIELD}" if use_kernel else f"dv.{VECTOR_FIELD}"
         ]
-        vals, ids, hits = _fused_vector(
-            vmat, live, qvecs, seg.base_doc,
-            k=k, cosine=cosine, dim=dim, use_kernel=use_kernel,
-            interpret=interpret,
-        )
-        profile.record("fused.vector")
+        with profile.span("exec.dispatch", program="_fused_vector"):
+            vals, ids, hits = _fused_vector(
+                vmat, live, qvecs, seg.base_doc,
+                k=k, cosine=cosine, dim=dim, use_kernel=use_kernel,
+                interpret=interpret,
+            )
+            profile.record("fused.vector")
         per_seg.append((vals, ids, hits))
     return _merge_segment_candidates(per_seg, n, k)
 
@@ -576,20 +591,22 @@ def exec_hybrid_fused(ctx, group: FamilyGroup, k: int) -> List[TopDocs]:
     use_kernel = kernel_enabled(k)
     interpret = resolve_interpret(None)
     terms = [q.term for q in group.queries]
-    qvecs = _vector_group_inputs(group, pad, dim, use_kernel)
-    for i, q in enumerate(group.queries):
-        qvecs[i, :dim] = q.vector.vector
-    idfs = np.asarray(
-        [ctx.idf(t) for t in terms] + [0.0] * pad, dtype=np.float32
-    )
-    alphas = np.asarray(
-        [q.alpha for q in group.queries] + [0.0] * pad, dtype=np.float32
-    )
+    with profile.span("exec.stage"):
+        qvecs = _vector_group_inputs(group, pad, dim, use_kernel)
+        for i, q in enumerate(group.queries):
+            qvecs[i, :dim] = q.vector.vector
+        idfs = np.asarray(
+            [ctx.idf(t) for t in terms] + [0.0] * pad, dtype=np.float32
+        )
+        alphas = np.asarray(
+            [q.alpha for q in group.queries] + [0.0] * pad, dtype=np.float32
+        )
     per_seg = []
     for seg in ctx.segments:
         if VECTOR_FIELD not in seg.doc_values:
             continue
-        meta = stage_term_meta(seg, terms, pad_rows=pad, tile=use_kernel)
+        with profile.span("exec.stage"):
+            meta = stage_term_meta(seg, terms, pad_rows=pad, tile=use_kernel)
         if meta is None:
             # match-all-live: the term scores nothing here, but the vector
             # half still ranks every live doc (dense BM25 sum = 0)
@@ -602,13 +619,14 @@ def exec_hybrid_fused(ctx, group: FamilyGroup, k: int) -> List[TopDocs]:
         vmat = st[
             f"tiled.dv.{VECTOR_FIELD}" if use_kernel else f"dv.{VECTOR_FIELD}"
         ]
-        vals, ids, hits = _fused_hybrid(
-            st["csr.docs"], st["csr.freqs"], dl, vmat, live,
-            starts, lengths, qvecs, idfs, alphas,
-            ctx.avgdl, ctx.k1, ctx.b, seg.base_doc,
-            p=p, k=k, cosine=cosine, dim=dim, use_kernel=use_kernel,
-            interpret=interpret,
-        )
-        profile.record("fused.hybrid")
+        with profile.span("exec.dispatch", program="_fused_hybrid"):
+            vals, ids, hits = _fused_hybrid(
+                st["csr.docs"], st["csr.freqs"], dl, vmat, live,
+                starts, lengths, qvecs, idfs, alphas,
+                ctx.avgdl, ctx.k1, ctx.b, seg.base_doc,
+                p=p, k=k, cosine=cosine, dim=dim, use_kernel=use_kernel,
+                interpret=interpret,
+            )
+            profile.record("fused.hybrid")
         per_seg.append((vals, ids, hits))
     return _merge_segment_candidates(per_seg, n, k)

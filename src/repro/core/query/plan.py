@@ -35,6 +35,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.core.analyzer import term_hash
+from repro.core.query import profile
 from repro.core.query.types import (
     BooleanQuery,
     FacetQuery,
@@ -139,17 +140,19 @@ class BatchPlan:
 
 
 def plan_batch(queries: Sequence[Query]) -> BatchPlan:
-    order: List[Tuple] = []
-    by_key: Dict[Tuple, FamilyGroup] = {}
-    for i, q in enumerate(queries):
-        key = family_key(q)
-        g = by_key.get(key)
-        if g is None:
-            g = by_key[key] = FamilyGroup(key=key, indices=[], queries=[])
-            order.append(key)
-        g.indices.append(i)
-        g.queries.append(q)
-    return BatchPlan(groups=[by_key[k] for k in order], n_queries=len(queries))
+    with profile.span("plan.batch", n=len(queries)):
+        order: List[Tuple] = []
+        by_key: Dict[Tuple, FamilyGroup] = {}
+        for i, q in enumerate(queries):
+            key = family_key(q)
+            g = by_key.get(key)
+            if g is None:
+                g = by_key[key] = FamilyGroup(key=key, indices=[], queries=[])
+                order.append(key)
+            g.indices.append(i)
+            g.queries.append(q)
+        return BatchPlan(groups=[by_key[k] for k in order],
+                         n_queries=len(queries))
 
 
 # ---------------------------------------------------------------------------

@@ -50,6 +50,7 @@ import numpy as np
 from repro.core.analyzer import Analyzer
 from repro.core.ingest_backend import BACKENDS, make_backend
 from repro.core.nrt import SearcherManager
+from repro.core.query import profile
 from repro.core.query.cache import SegmentDeviceCache
 from repro.core.query.exec import _finalize_scored, merge_topk
 from repro.core.query.plan import FamilyGroup, plan_batch
@@ -469,7 +470,11 @@ class ShardedSearcher:
         for group in plan.groups:
             # instance dispatch: a shard view holding a live tail scores
             # (committed ∪ live) through repro.core.query.live
-            shard_tds = [s.execute_group(group, k) for s in self.searchers]
+            shard_tds = []
+            for sid, s in enumerate(self.searchers):
+                with profile.span("sharded.shard", shard=sid,
+                                  family=group.kind):
+                    shard_tds.append(s.execute_group(group, k))
             for qi, td in zip(
                 group.indices, self._merge_shards(group, shard_tds, k)
             ):
@@ -478,6 +483,15 @@ class ShardedSearcher:
 
     # -- cross-shard merge --------------------------------------------------
     def _merge_shards(
+        self,
+        group: FamilyGroup,
+        shard_tds: List[List[TopDocs]],
+        k: int,
+    ) -> List[TopDocs]:
+        with profile.span("sharded.merge", family=group.kind):
+            return self._merge(group, shard_tds, k)
+
+    def _merge(
         self,
         group: FamilyGroup,
         shard_tds: List[List[TopDocs]],
@@ -514,7 +528,8 @@ class ShardedSearcher:
                     vals[qi, si * k : si * k + c] = td.scores[:c]
                     ids[qi, si * k : si * k + c] = emap[td.doc_ids[:c]]
                 totals[qi] += td.total_hits
-        mv, mi = merge_topk(jnp.asarray(vals), jnp.asarray(ids), k)
+        with profile.span("exec.dispatch", program="merge_topk"):
+            mv, mi = merge_topk(jnp.asarray(vals), jnp.asarray(ids), k)
         # same trim-and-box convention as the per-segment merge path
         return _finalize_scored(mv, mi, totals, n)
 
@@ -546,10 +561,10 @@ class ShardedSearcherManager:
         ]
         self.device_caches = caches
         self.managers = [
-            SearcherManager(w, use_pallas=use_pallas, device_cache=c)
-            for w, c in zip(writer.writers, caches)
+            SearcherManager(w, use_pallas=use_pallas, device_cache=c,
+                            shard=sid)
+            for sid, (w, c) in enumerate(zip(writer.writers, caches))
         ]
-        self.reopen_times: List[float] = []
         self._current: Optional[ShardedSearcher] = None
         self._view_gens: List[tuple] = []
         self._rebind()
@@ -598,9 +613,7 @@ class ShardedSearcherManager:
         targets = range(len(self.managers)) if shard is None else [shard]
         dts = [self.managers[i].maybe_reopen(force_flush) for i in targets]
         self._rebind()
-        dt = max(dts)
-        self.reopen_times.append(dt)
-        return dt
+        return max(dts)
 
 
 # ---------------------------------------------------------------------------

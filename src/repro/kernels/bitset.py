@@ -75,5 +75,6 @@ def _bitset_combine_blocks(bitmaps, mode, interpret):
             jax.ShapeDtypeStruct((nb, BLOCK_COLS), jnp.int32),
         ],
         interpret=interpret,
+        name="bitset_combine",
     )(b3)
     return combined.reshape(w), counts[:, 0]

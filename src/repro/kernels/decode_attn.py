@@ -130,5 +130,6 @@ def decode_attn(
             pltpu.VMEM((g, dv), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
+        name="decode_attn",
     )(kv_len2, qf, kf, vf)
     return out.reshape(bsz, hkv, g, dv)

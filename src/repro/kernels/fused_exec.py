@@ -102,10 +102,11 @@ _TILE = pl.BlockSpec((1, BLOCK_ROWS, BLOCK_COLS), lambda q, i: (q, i, 0))
 _SHARED_TILE = pl.BlockSpec((BLOCK_ROWS, BLOCK_COLS), lambda q, i: (i, 0))
 
 
-def _blocks_call(kernel, bsz, nb, in_specs, interpret):
-    """``pallas_call`` over grid (B, NB) with the per-block winner outputs:
-    ((B, NB, 128) vals, (B, NB, 128) flat idx, (B, NB, 128) lane-0 hit
-    counts), each query's rows resident across the block axis."""
+def _blocks_call(kernel, bsz, nb, in_specs, interpret, name):
+    """``pallas_call`` named ``name`` over grid (B, NB) with the per-block
+    winner outputs: ((B, NB, 128) vals, (B, NB, 128) flat idx, (B, NB, 128)
+    lane-0 hit counts), each query's rows resident across the block
+    axis."""
     resident = pl.BlockSpec((1, nb, OUT_K), lambda q, i: (q, 0, 0))
     return pl.pallas_call(
         kernel,
@@ -121,6 +122,7 @@ def _blocks_call(kernel, bsz, nb, in_specs, interpret):
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
+        name=name,
     )
 
 
@@ -168,7 +170,7 @@ def term_topk_tiles(g, freqs, idfs, avgdl, k1, b, k, interpret):
     f3 = freqs.reshape(bsz, nb * BLOCK_ROWS, BLOCK_COLS)
     vals, idx, cnt = _blocks_call(
         functools.partial(_term_kernel, k=k), bsz, nb,
-        [_SMEM, _SMEM, _TILE, _TILE], interpret,
+        [_SMEM, _SMEM, _TILE, _TILE], interpret, "fused_term",
     )(params, idfs.astype(jnp.float32), g3, f3)
     return vals, idx, cnt[..., 0]
 
@@ -206,7 +208,7 @@ def bool_topk_tiles(dense, count, live, k, n_terms, conjunctive, interpret):
         functools.partial(
             _bool_kernel, k=k, n_terms=n_terms, conjunctive=conjunctive
         ),
-        bsz, nb, [_TILE, _TILE, _SHARED_TILE], interpret,
+        bsz, nb, [_TILE, _TILE, _SHARED_TILE], interpret, "fused_bool",
     )(d3, c3, l3)
     return vals, idx, cnt[..., 0]
 
@@ -235,7 +237,7 @@ def sort_topk_tiles(matched, dv, k, interpret):
     v3 = dv.reshape(nb * BLOCK_ROWS, BLOCK_COLS)
     vals, idx, cnt = _blocks_call(
         functools.partial(_sort_kernel, k=k), bsz, nb,
-        [_TILE, _SHARED_TILE], interpret,
+        [_TILE, _SHARED_TILE], interpret, "fused_sort",
     )(m3, v3)
     return vals, idx, cnt[..., 0]
 
@@ -276,6 +278,7 @@ def range_topk_tiles(dv, live, los, his, k, interpret):
     vals, idx, cnt = _blocks_call(
         functools.partial(_range_kernel, k=k), bsz, nb,
         [_SMEM, _SMEM, _SHARED_TILE, _SHARED_TILE], interpret,
+        "fused_range",
     )(los, his, v3, l3)
     return vals, idx, cnt[..., 0]
 
@@ -340,5 +343,6 @@ def facet_hist_tiles(matched, bins, n_bins, interpret):
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="fused_facet",
     )(m3, b3)
     return hist.sum(-1)[:, :n_bins], cnt[..., 0]

@@ -117,7 +117,7 @@ def vector_topk_tiles(vmat, live, qvecs, k, cosine=False, dim=None,
     vals, idx, cnt = _blocks_call(
         functools.partial(_vector_kernel, k=k, cosine=cosine, dim=dim),
         bsz, nb, [_query_spec(dp), _vmat_spec(dp), _SHARED_TILE],
-        interpret,
+        interpret, "vector_topk",
     )(qvecs.reshape(bsz, 1, dp), v3, l3)
     return vals, idx, cnt[..., 0]
 
@@ -170,6 +170,6 @@ def hybrid_topk_tiles(dense, vmat, live, qvecs, alphas, k, cosine=False,
         bsz, nb,
         [_query_spec(dp), _SMEM, _TILE, _vmat_spec(dp),
          _SHARED_TILE],
-        interpret,
+        interpret, "hybrid_topk",
     )(qvecs.reshape(bsz, 1, dp), alphas.astype(jnp.float32), d3, v3, l3)
     return vals, idx, cnt[..., 0]

@@ -79,6 +79,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.core.query import profile
 from repro.core.query.plan import bucket_batch
 from repro.core.query.types import Query, TopDocs
 
@@ -158,6 +159,7 @@ class PendingSearch:
     error: Optional[BaseException] = None
     searcher: Any = None  # the wave's bound ShardedSearcher (oracle input)
     wave: int = -1
+    t_submit_ns: int = 0  # submit time, stamped while spans are recorded
 
     def result(self, timeout: Optional[float] = None) -> TopDocs:
         if not self._done.wait(timeout):
@@ -352,6 +354,8 @@ class SearchFrontend:
                 raise OverloadError(depth, self.shed_watermark)
             self._seqno += 1
             req = PendingSearch(query=query, k=int(k), seqno=self._seqno)
+            if profile.recording():
+                req.t_submit_ns = time.perf_counter_ns()
             self._search_q.append(req)
             self._stats["queries"] += 1
             self._work_cv.notify()
@@ -482,11 +486,12 @@ class SearchFrontend:
                 wave = []
                 while self._search_q and len(wave) < self.max_wave:
                     wave.append(self._search_q.popleft())
+            t_pop = time.perf_counter_ns() if profile.recording() else 0
             try:
                 if ingest_op is not None:
                     self._run_ingest(ingest_op)
                 if wave:
-                    self._run_wave(wave)
+                    self._run_wave(wave, t_pop)
             finally:
                 with self._lock:
                     self._busy = False
@@ -495,6 +500,10 @@ class SearchFrontend:
 
     # one writer-op application; every failure lands on the ticket, typed
     def _run_ingest(self, req: PendingIngest) -> None:
+        with profile.span("frontend.ingest", trace=req.seqno, kind=req.kind):
+            self._apply_ingest(req)
+
+    def _apply_ingest(self, req: PendingIngest) -> None:
         try:
             if req.kind == "add":
                 req.value = self.writer.add_documents(req.docs)
@@ -558,6 +567,10 @@ class SearchFrontend:
         """Per-shard search-at-ack reopen, skipping shards already marked
         failed; a shard that fails HERE is marked and skipped next time —
         queries keep running on the last good snapshot either way."""
+        with profile.span("frontend.reopen"):
+            self._reopen_shards()
+
+    def _reopen_shards(self) -> None:
         n = getattr(self.writer, "n_shards", len(self.manager.managers))
         for sid in range(n):
             with self._lock:
@@ -578,10 +591,7 @@ class SearchFrontend:
             self._stats["reopens"] += 1
         self._last_reopen = time.perf_counter()
 
-    def _run_wave(self, wave: List[PendingSearch]) -> None:
-        self._maybe_reopen_policy()
-        searcher = self.manager.searcher  # the wave's bound snapshot
-        kmax = max(r.k for r in wave)
+    def _run_wave(self, wave: List[PendingSearch], t_pop: int) -> None:
         with self._lock:
             self._stats["waves"] += 1
             self._stats["wave_queries"] += len(wave)
@@ -589,6 +599,19 @@ class SearchFrontend:
                 self._stats["max_wave_seen"], len(wave)
             )
             wave_no = int(self._stats["waves"])
+        if t_pop:  # each query's wait, submit to its wave's pop
+            for r in wave:
+                if r.t_submit_ns:
+                    profile.add("frontend.queue", r.t_submit_ns, t_pop,
+                                trace=r.seqno, seqno=r.seqno, wave=wave_no)
+        with profile.span("frontend.wave", trace=wave_no, wave=wave_no,
+                          n=len(wave), padded=bucket_batch(len(wave))):
+            self._search_wave(wave, wave_no)
+
+    def _search_wave(self, wave: List[PendingSearch], wave_no: int) -> None:
+        self._maybe_reopen_policy()
+        searcher = self.manager.searcher  # the wave's bound snapshot
+        kmax = max(r.k for r in wave)
         try:
             tds = searcher.search_batch([r.query for r in wave], k=kmax)
         except Exception as exc:  # noqa: BLE001 — every ticket must resolve
@@ -600,11 +623,12 @@ class SearchFrontend:
                 r.error = err
                 r._done.set()
             return
-        for r, td in zip(wave, tds):
-            r.result_td = _trim(td, r.k)
-            r.searcher = searcher
-            r.wave = wave_no
-            r._done.set()
+        with profile.span("frontend.deliver"):
+            for r, td in zip(wave, tds):
+                r.result_td = _trim(td, r.k)
+                r.searcher = searcher
+                r.wave = wave_no
+                r._done.set()
 
     # power-of-two coalescing helper, exported for the benchmark's wave
     # accounting (the planner pads the batch dimension the same way)

@@ -4,7 +4,9 @@ Interpret mode on the CPU checks what the kernels compute, not whether
 Mosaic accepts them: block shapes must obey the (8, 128) tiling rule and a
 kernel may not gather.  These tests compile each kernel for a described
 (not attached) v5e chip at the widths ``chip_smoke.py`` serves and assert
-the compiled program carries the kernel as a ``tpu_custom_call``.
+the compiled program carries the kernel as a ``tpu_custom_call`` under
+its stable ``name``, and that the jitted programs the benchmark's trace
+reduction reads keep their names.
 
 The topology is described inside a module fixture only: one process at a
 time may load the TPU compiler library, so nothing here may touch it while
@@ -12,12 +14,15 @@ modules are imported.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.query import exec as qexec
+from repro.core.query import fused
 from repro.kernels import fused_exec as fk
 from repro.kernels import vector_topk as vk
 
@@ -57,6 +62,14 @@ def one_chip():
         finally:
             jax.config.update("jax_enable_compilation_cache", prev)
             compilation_cache.reset_cache()
+
+
+#: family -> the ``pallas_call`` name its kernel carries into the trace
+KERNEL_NAMES = {
+    "term": "fused_term", "bool": "fused_bool", "sort": "fused_sort",
+    "range": "fused_range", "facet": "fused_facet",
+    "vector": "vector_topk", "hybrid": "hybrid_topk",
+}
 
 
 def _cases():
@@ -110,5 +123,41 @@ def _cases():
 def test_kernel_compiles_for_v5e(one_chip, family):
     fn, shapes = _cases()[family]
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    name = KERNEL_NAMES[family]
+    assert re.search(
+        rf"%{name}(\.\d+)? = .*custom_call_target=\"tpu_custom_call\"", text
+    ), f"kernel name {name!r} lost in lowering"
+
+
+def _program(name, sharding):
+    """(jitted function, abstract arguments, static keywords) of the
+    program the benchmark reads under jit name ``name``, at served
+    widths: one shard's vector column, a 2-segment merge, a 2-shard
+    merge."""
+    b, nv = BATCH, VEC_DOCS
+
+    def a(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    if name == "_fused_vector":
+        return fused._fused_vector, (
+            a((nv, DIM), F32), a((nv,), I32), a((b, DIM), F32), a((), I32),
+        ), dict(k=K, cosine=False, dim=DIM, use_kernel=True, interpret=False)
+    if name == "_concat_merge":
+        return qexec._concat_merge, (
+            (a((b, K), F32),) * 2, (a((b, K), I32),) * 2, (a((b,), I32),) * 2,
+        ), dict(k=K)
+    return qexec.merge_topk, (a((b, 2 * K), F32), a((b, 2 * K), I32)), dict(
+        k=K)
+
+
+@pytest.mark.parametrize("program", ["_fused_vector", "_concat_merge",
+                                     "merge_topk"])
+def test_program_names_survive_for_v5e(one_chip, program):
+    """The trace reduction and the result line's breakdown find these
+    programs by their jit names (``jit_<name>``)."""
+    fn, args, static = _program(program, one_chip)
+    text = fn.lower(*args, **static).compile().as_text()
+    assert text.startswith(f"HloModule jit_{program},"), text[:120]
