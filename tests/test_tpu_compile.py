@@ -35,6 +35,10 @@ SEG_DOCS = -(-500_000 // 2 // fk.BLOCK) * fk.BLOCK  # doc space = longest row
 VEC_DOCS = -(-100_000 // 2 // fk.BLOCK) * fk.BLOCK
 DIM = 768
 MONTH_BINS = 12
+# the benchmark's dense cell: one shard's share of 500k passages, full
+# 64-query waves
+CELL_WAVE = 64
+CELL_VEC_DOCS = -(-500_000 // 2 // fk.BLOCK) * fk.BLOCK
 
 I32, F32 = jnp.int32, jnp.float32
 
@@ -68,7 +72,8 @@ def one_chip():
 KERNEL_NAMES = {
     "term": "fused_term", "bool": "fused_bool", "sort": "fused_sort",
     "range": "fused_range", "facet": "fused_facet",
-    "vector": "vector_topk", "hybrid": "hybrid_topk",
+    "vector": "vector_topk", "vector_cell": "vector_topk",
+    "hybrid": "hybrid_topk",
 }
 
 
@@ -107,6 +112,13 @@ def _cases():
             ),
             [((nv, DIM), F32), ((nv,), I32), ((b, DIM), F32)],
         ),
+        "vector_cell": (
+            lambda v, live, q: vk.vector_topk_tiles(
+                v, live, q, K, False, DIM, False
+            ),
+            [((CELL_VEC_DOCS, DIM), F32), ((CELL_VEC_DOCS,), I32),
+             ((CELL_WAVE, DIM), F32)],
+        ),
         "hybrid": (
             lambda d, v, live, q, a: vk.hybrid_topk_tiles(
                 d, v, live, q, a, K, False, DIM, False
@@ -118,7 +130,9 @@ def _cases():
 
 
 @pytest.mark.parametrize(
-    "family", ["term", "bool", "sort", "range", "facet", "vector", "hybrid"]
+    "family",
+    ["term", "bool", "sort", "range", "facet", "vector", "vector_cell",
+     "hybrid"],
 )
 def test_kernel_compiles_for_v5e(one_chip, family):
     fn, shapes = _cases()[family]
