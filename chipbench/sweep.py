@@ -11,10 +11,16 @@ commit interval shows whether the queue a commit leaves behind clears
 before the next one.  A rate is sustained when
 
 * no query was shed or failed, and every acked batch was read back;
-* the last answer due in the window arrived within 1 s of its close.
+* the last answer due in the window arrived within 1 s of its close;
+* the last ack of a batch due in the window arrived within 1 s of its
+  close: the writer ops share the one dispatcher with the waves, so a
+  query rate can leave every answer on time while the ack queue grows
+  all through the window.
 
-The longest commit seen sets how many queries queue behind one, hence
-the cell's ``shed_watermark``.
+Each window also reports the queries that arrived during its longest
+commit and the most queries outstanding at once (an unanswered one counts
+until the grace period ends): the readings the cell's ``shed_watermark``
+is set from.
 
 Each window prints one JSON line; the last line names the knee, the
 highest sustained rate below the first that was not.  Runs only on a TPU,
@@ -34,15 +40,39 @@ import numpy as np
 from run import DeviceError, GRACE_S, M, Served, log, summarize  # noqa: E402
 
 
+def max_outstanding(queries, until: float) -> int:
+    """Most queries sent and not yet answered at any one time; a query
+    never answered is outstanding until ``until``, and a shed one (its
+    ``done`` stamped at its send) never is."""
+    sent = [r for r in queries if r.sent and r.done != r.sent]
+    ev = sorted([(r.sent, 1) for r in sent]
+                + [(r.done or until, -1) for r in sent])
+    return int(max(np.cumsum([d for _, d in ev]), default=0))
+
+
+def commit_arrivals(rec) -> int:
+    """Queries due while the longest of the window's commits ran."""
+    if not rec.commits:
+        return 0
+    c0, c1, _ = max(rec.commits, key=lambda c: c[1] - c[0])
+    return sum(1 for r in rec.queries if c0 <= r.sched <= c1)
+
+
 def sustained(rec, stats) -> dict:
     ok = [r for r in rec.queries if r.error is None and r.done]
     last = sorted(ok, key=lambda r: r.sched)[-max(1, len(ok) // 4):]
     drain = max((r.done for r in ok), default=rec.end) - rec.end
+    ack_drain = max((b.acked for b in rec.batches if b.acked),
+                    default=rec.end) - rec.end
     verdict = (stats["failed_queries"] == 0 and stats["failed_batches"] == 0
-               and drain <= 1.0)
+               and drain <= 1.0 and ack_drain <= 1.0)
     return {"p50_last_quarter_ms": 1e3 * float(np.median(
                 [r.done - r.sched for r in last])) if last else None,
-            "drain_after_close_s": drain, "sustained": bool(verdict)}
+            "drain_after_close_s": drain, "ack_drain_after_close_s": ack_drain,
+            "max_outstanding": max_outstanding(rec.queries + rec.probes,
+                                               rec.end + GRACE_S),
+            "commit_arrivals": commit_arrivals(rec),
+            "sustained": bool(verdict)}
 
 
 def main(argv: Optional[List[str]] = None) -> int:

@@ -9,7 +9,7 @@ import pytest
 from repro.core.query.types import TopDocs
 from repro.core.sharded import ShardedSearcher, ShardedSearcherManager
 
-from chipbench.tests.tiny import DENSE, NRT, run_tiny
+from chipbench.tests.tiny import DENSE, NRT, run_tiny, tiny_cell
 
 
 def _alter(td: TopDocs) -> TopDocs:
@@ -79,18 +79,47 @@ def state_unchanged(monkeypatch):
                         lambda self, shard=None, force_flush=False: 0.0)
 
 
+#: the busy cell can outrun a loaded CPU: late answers are waited for
+BUSY_GRACE_S = 20.0
+
+
+def busy_nrt_cell() -> dict:
+    """The tiny NRT cell with waves that hold several queries (150 q/s,
+    waves of up to 16), as the cell's own waves do on the chip, and every
+    answer checked: the tiny cell's 12 q/s leaves nearly every wave one
+    query long, where a fault inside a wave cannot show."""
+    cell = tiny_cell(NRT)
+    cell["traffic"]["rate_qps"] = 150
+    cell["traffic"]["frontend"]["max_wave"] = 16
+    cell["traffic"]["check_sample"] = 10_000
+    return cell
+
+
 @pytest.mark.parametrize("name,fault", [
     (NRT, answer_altered),
+    (NRT, last_id_altered),
     (NRT, exchange_left_out),
     (NRT, state_unchanged),
+    (NRT, half_batch_left_out),
     (DENSE, answer_altered),
     (DENSE, last_id_altered),
     (DENSE, exchange_left_out),
     (DENSE, half_batch_left_out),
 ])
 def test_planted_fault_fails_the_run(name, fault, monkeypatch):
-    out = run_tiny(name, seed=2**31 + 29, grace_s=2.0,
+    busy = name == NRT and fault is half_batch_left_out
+    out = run_tiny(name, seed=2**31 + 29,
+                   grace_s=BUSY_GRACE_S if busy else 2.0,
+                   cell=busy_nrt_cell() if busy else None,
                    fault=lambda eng, fe: fault(monkeypatch))
     assert out["correct"] is False, out["checks"]
     broken = [k for k, (v, lim) in out["checks"].items() if v > lim]
     assert broken, out["checks"]
+
+
+def test_busy_nrt_cell_is_sound_without_a_fault():
+    """The busier tiny NRT cell that the half-wave fault runs in is
+    correct when nothing is broken, so that fault's failure is its own."""
+    out = run_tiny(NRT, seed=2**31 + 29, grace_s=BUSY_GRACE_S,
+                   cell=busy_nrt_cell())
+    assert out["correct"] is True, out["checks"]

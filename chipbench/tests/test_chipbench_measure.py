@@ -1,4 +1,5 @@
-"""Required bytes, the trace reduction and the per-layer readers."""
+"""Required bytes, the trace reduction, the per-layer readers and the
+knee sweep's verdict."""
 
 import json
 import time
@@ -9,6 +10,7 @@ import pytest
 from chipbench import manifest as M
 from chipbench import readings as RD
 from chipbench import trace as T
+from chipbench.drive import Batch, Rec, Recording
 from chipbench.roofline import vector_wave_bytes
 
 FIXTURE = Path(__file__).parent / "data" / "tpu_trace_events.json"
@@ -129,3 +131,45 @@ def test_every_manifest_metric_has_a_reader_and_unit():
     for e in m["per_layer"]:
         assert callable(M.reader(e["name"]))
         assert e["moves"] in {x["name"] for x in m["end_to_end"]}
+
+
+def test_sweep_holds_a_growing_ack_queue_unsustained(monkeypatch):
+    """Answers on time do not make a rate sustained while the acks fall
+    behind: the last batch due in the window must be acked within 1 s of
+    its close too."""
+    monkeypatch.syspath_prepend(str(M.HERE))  # as the script runs
+    import sweep
+
+    rec = Recording(start=0.0, end=10.0)
+    rec.queries = [Rec("q", i, float(i), sent=float(i), done=i + 1.5)
+                   for i in range(10)]
+    rec.probes = [Rec("p", 0, 3.2, sent=3.2, done=3.4)]
+    rec.batches = [Batch(b, float(b), acked=b + 0.2) for b in range(10)]
+    ok = {"failed_queries": 0, "failed_batches": 0}
+    v = sweep.sustained(rec, ok)
+    assert v["sustained"] and v["drain_after_close_s"] == pytest.approx(0.5)
+    # queries 2 and 3 and the probe are outstanding together at 3.3 s
+    assert v["max_outstanding"] == 3
+    assert v["commit_arrivals"] == 0
+    rec.batches[-1].acked = 12.0
+    v = sweep.sustained(rec, ok)
+    assert not v["sustained"]
+    assert v["ack_drain_after_close_s"] == pytest.approx(2.0)
+
+
+def test_sweep_counts_unanswered_queries_as_outstanding(monkeypatch):
+    """Under overload a query that is never answered stays outstanding to
+    the end of the grace period, and a shed one (done at its send) never
+    counts; the queries due during the longest commit are counted."""
+    monkeypatch.syspath_prepend(str(M.HERE))
+    import sweep
+
+    rec = Recording(start=0.0, end=10.0)
+    rec.queries = ([Rec("q", i, float(i), sent=float(i), done=i + 0.5)
+                    for i in range(10)]
+                   + [Rec("q", 10 + i, 2.0 + i, sent=2.0 + i)
+                      for i in range(3)]
+                   + [Rec("q", 13, 6.1, sent=6.1, done=6.1)])
+    rec.commits = [(1.0, 1.2, None), (4.0, 7.5, None)]
+    assert sweep.max_outstanding(rec.queries, rec.end + 60.0) == 4
+    assert sweep.commit_arrivals(rec) == 6  # 4, 5, 6, 7, the 4.0 and 6.1

@@ -52,6 +52,19 @@ def test_control_in_lower_precision_fails(sound):
     assert gap > 10 * out["checks"]["score_gap"][0]
 
 
+def test_every_end_to_end_metric_is_one_the_run_fills():
+    """Every end-to-end name in BENCHMARK.json is a number the untraced
+    run computes: an open loop with ingest, asked for all of them, fills
+    each, so no cell can list one that reads nothing."""
+    man = M.load_manifest()
+    for m in man["end_to_end"]:
+        m.pop("workloads", None)
+    out = run_tiny(NRT, seed=2**31 + 41, manifest=man)
+    assert out["correct"] is True, out["checks"]
+    assert set(out["metrics"]) == {m["name"] for m in man["end_to_end"]}
+    assert all(v["value"] > 0 for v in out["metrics"].values())
+
+
 def test_traced_run_reads_per_layer_metrics():
     out = run_tiny(DENSE, seed=5, trace=True)
     assert out["correct"] is True

@@ -101,6 +101,44 @@ def test_new_metrics_are_in_the_manifest_for_the_dense_cell():
         assert e["source"] == "program_span"
 
 
+def _writer_ops():
+    """A window's dispatcher writer ops, in ms: two acked adds, a commit
+    (an ingest op of another kind) and two reopens."""
+    m = MS
+    return [
+        _span(1, "frontend.ingest", 0, 4 * m, trace=7, kind="add"),
+        _span(2, "frontend.ingest", 10 * m, 16 * m, trace=8, kind="add"),
+        _span(3, "frontend.ingest", 20 * m, 920 * m, trace=9, kind="commit"),
+        _span(4, "frontend.reopen", 930 * m, 933 * m),
+        _span(5, "nrt.reopen", 930 * m, 931 * m, 4, shard=0),
+        _span(6, "frontend.reopen", 940 * m, 947 * m),
+        _span(7, "frontend.wave", 950 * m, 960 * m, trace=1),
+    ]
+
+
+@pytest.mark.parametrize("name,want", [("ingest_ms.nrt", 5.0),
+                                       ("reopen_ms.nrt", 5.0)])
+def test_writer_op_readers_on_synthetic_spans(monkeypatch, name, want):
+    """Only acked adds count as ingest (the commit is an ingest op too,
+    and 180x longer); the reopen is the front end's whole cross-shard
+    one, not a shard's part of it."""
+    r = RD.Readings(frontend={}, dispatches={}, commit_ms=[],
+                    trace={"busy_s": 0.1, "window_s": 1.0, "programs": {},
+                           "gaps": []})
+    monkeypatch.setattr(profile, "spans", _writer_ops)
+    assert M.reader(name)(r) == pytest.approx(want)
+    # a window with no such span reads nothing
+    monkeypatch.setattr(profile, "spans", lambda: [
+        s for s in _writer_ops() if s.name == "frontend.wave"])
+    assert M.reader(name)(r) is None
+    monkeypatch.setattr(profile, "spans", list)
+    assert M.reader(name)(r) is None
+    # spans are read only against a device trace
+    monkeypatch.setattr(profile, "spans", _writer_ops)
+    r.trace = None
+    assert M.reader(name)(r) is None
+
+
 def _fixture():
     rec = json.loads(FIXTURE.read_text())
     ev = T.events_from_records(rec["events"], rec["sync_ns"])

@@ -7,8 +7,12 @@ import numpy as np
 import pytest
 
 from chipbench import corpus as C
+from chipbench import manifest as M
 from chipbench import traffic as TR
 from chipbench.tests.tiny import DENSE, NRT, cell_files
+
+#: Solr's shipped ``autoCommit`` ``maxTime`` (15,000 ms): the NRT mix's cadence
+AUTO_COMMIT_S = 15.0
 
 
 @pytest.fixture(scope="module")
@@ -112,3 +116,42 @@ def test_folded_zipf_law_matches_numpy_draw():
     draw = np.random.default_rng(0).zipf(1.3, size=400_000) % 30000
     emp = np.bincount(draw, minlength=30000) / len(draw)
     assert np.abs(emp[:20] - p[:20]).max() < 3e-3
+
+
+def _listed_cells():
+    man = M.load_manifest()
+    return man, [M.cell(man, w["name"]) for w in man["workloads"]]
+
+
+def test_no_cell_in_the_manifest_holds_a_placeholder():
+    _, cells = _listed_cells()
+    for cell in cells:
+        assert cell["traffic"].get("unmeasured", []) == [], cell["workload"]
+
+
+def test_commits_fall_inside_the_window_at_the_stated_cadence():
+    """Every commit offset lies inside a run's window, and the NRT mix
+    commits every 15 s through the whole window, as Solr's autoCommit."""
+    man, cells = _listed_cells()
+    seconds = man["run_seconds"]
+    for cell in cells:
+        at = cell["traffic"].get("commit_at_s", [])
+        assert all(0 < c < seconds for c in at), cell["workload"]
+    at = cell_files(NRT)["traffic"]["commit_at_s"]
+    assert np.allclose(np.diff(at), AUTO_COMMIT_S)
+    assert at[0] < AUTO_COMMIT_S and seconds - at[-1] < AUTO_COMMIT_S
+
+
+def test_nrt_tail_stays_in_one_bucket_per_shard_through_a_run():
+    """The live tail at the window's start, the rehearsal's ingest and a
+    whole window's stay under the same power of two per shard, so the
+    tail's programs keep the shapes the warm-up compiled."""
+    nrt = cell_files(NRT)
+    mix, shards = nrt["traffic"], nrt["config"]["deployment"]["n_shards"]
+    seconds = M.load_manifest()["run_seconds"]
+    ing = mix["ingest"]
+    start = ing["tail_docs_at_start"] / shards
+    end = start + ing["docs_per_s"] * (
+        seconds + mix["warmup"]["rehearsal_s"]) / shards
+    bucket = 1 << int(np.ceil(np.log2(start)))
+    assert start > bucket // 2 and end < bucket, (start, end, bucket)
